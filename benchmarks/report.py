@@ -20,10 +20,6 @@ import re
 BENCH_HIGHLIGHTS = (r"speedup|taos_per_s|attainment|p99|makespan|conserved"
                     r"|violations|exchanges|completed")
 
-PEAK_FLOPS = 197e12      # bf16 FLOP/s per chip (v5e-class, per the brief)
-HBM_BW = 819e9           # B/s per chip
-ICI_BW = 50e9            # B/s per link
-
 SHAPE_TOKENS = {          # tokens processed per step (global)
     "train_4k": 4096 * 256,
     "prefill_32k": 32768 * 32,
@@ -64,14 +60,18 @@ def load_cells(d: pathlib.Path) -> list[dict]:
 
 
 def roofline_row(rec: dict) -> dict:
+    from repro.launch.device_peaks import device_peaks
+    from repro.launch.mesh import PRODUCTION_DEVICE_KIND
+
+    peaks = device_peaks(PRODUCTION_DEVICE_KIND)
     chips = rec["chips"]
     flops_dev = rec["flops"]
     bytes_dev = rec["bytes_accessed"]
     coll = rec.get("collectives", {})
     coll_dev = sum(v for k, v in coll.items() if k != "count")
-    t_comp = flops_dev / PEAK_FLOPS
-    t_mem = bytes_dev / HBM_BW
-    t_coll = coll_dev / ICI_BW
+    t_comp = flops_dev / peaks.flops_bf16
+    t_mem = bytes_dev / peaks.hbm_bw
+    t_coll = coll_dev / peaks.ici_link_bw
     dominant = max((t_comp, "compute"), (t_mem, "memory"),
                    (t_coll, "collective"))[1]
     mf = model_flops_global(rec["arch"], rec["shape"])
@@ -177,9 +177,15 @@ def main() -> None:
     oks = [roofline_row(r) for r in cells if r["status"] == "ok"]
     if not oks:
         return
+    from repro.launch.device_peaks import device_peaks
+    from repro.launch.mesh import PRODUCTION_DEVICE_KIND
+
+    peaks = device_peaks(PRODUCTION_DEVICE_KIND)
     print("### Roofline —", args.mesh,
-          "(terms in seconds/step/device; constants: 197 TF bf16, "
-          "819 GB/s HBM, 50 GB/s ICI)")
+          f"(terms in seconds/step/device; peaks of {PRODUCTION_DEVICE_KIND} "
+          f"per {peaks.source}: {peaks.flops_bf16 / 1e12:g} TF bf16, "
+          f"{peaks.hbm_bw / 1e9:g} GB/s HBM, "
+          f"{peaks.ici_link_bw / 1e9:g} GB/s ICI per link)")
     print()
     print("| arch | shape | compute s | memory s | collective s | bound | "
           "MODEL_FLOPS | useful ratio | roofline frac |")

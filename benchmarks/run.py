@@ -3,8 +3,9 @@ benches.  Prints ``name,us_per_call,derived`` CSV rows (derived = the
 figure's own metric, e.g. TAOs/s for Fig 6).
 
   fig4   — kernel profiling (paper Fig 4): throughput vs (chains x width x
-           core class) on the calibrated simulator, plus real Pallas-kernel
-           wall-times on this host (oracle path).
+           core class) on the calibrated simulator, plus real kernel
+           wall-times on the auto-dispatched ops (Pallas on a TPU, XLA
+           elsewhere), each row labelled with the device it ran on.
   fig6   — randomized DAGs (paper Fig 6): 3 parallelism degrees x all
            scheduling policies, width hints 1 and 4.
   tab1/2 — task-molding impact (paper Tables 1 and 2).
@@ -78,13 +79,16 @@ def fig4_kernel_profile() -> None:
 
 
 def fig4_real_kernels() -> None:
-    """Real kernel wall-times on this host (XLA oracle path, CPU)."""
+    """Real kernel wall-times of the auto-dispatched ops (Pallas on a TPU,
+    the XLA reference elsewhere); ``derived`` names the device."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     from repro.kernels import ops
 
     r = np.random.default_rng(0)
+    dev = jax.devices()[0]
+    device = f"{dev.platform}:{dev.device_kind}"
 
     def bench(name, fn, *args, iters=20):
         fn(*args).block_until_ready()          # compile + warm
@@ -93,15 +97,15 @@ def fig4_real_kernels() -> None:
             out = fn(*args)
         out.block_until_ready()
         us = (time.perf_counter() - t0) / iters * 1e6
-        emit(f"fig4.real.{name}", us, "host_cpu")
+        emit(f"fig4.real.{name}", us, device)
 
     a = jnp.asarray(r.standard_normal((512, 512)), jnp.float32)
     b = jnp.asarray(r.standard_normal((512, 512)), jnp.float32)
-    bench("matmul_512", lambda x, y: ops.matmul(x, y, force="ref"), a, b)
+    bench("matmul_512", ops.matmul, a, b)
     big = jnp.asarray(r.standard_normal((4096, 512)), jnp.float32)
-    bench("copy_8MB", lambda x: ops.copy(x, force="ref"), big)
+    bench("copy_8MB", ops.copy, big)
     s = jnp.asarray(r.standard_normal((64, 1024)), jnp.float32)
-    bench("sort_64x1024", lambda x: ops.sort_rows(x, force="ref"), s)
+    bench("sort_64x1024", ops.sort_rows, s)
 
 
 # ---------------------------------------------------------------------------
@@ -1112,12 +1116,13 @@ def train_bench() -> None:
 # ---------------------------------------------------------------------------
 # roofline (from dry-run artifacts)
 # ---------------------------------------------------------------------------
-PEAK_FLOPS = 197e12          # bf16 / chip (v5e-class, per the brief)
-HBM_BW = 819e9               # B/s per chip
-ICI_BW = 50e9                # B/s per link
-
-
 def roofline(dryrun_dir: str = "experiments/dryrun/single_pod") -> None:
+    """Roofline terms of the dry-run cells against the peaks of the chip
+    the production mesh stands for."""
+    from repro.launch.device_peaks import device_peaks
+    from repro.launch.mesh import PRODUCTION_DEVICE_KIND
+
+    peaks = device_peaks(PRODUCTION_DEVICE_KIND)
     d = pathlib.Path(dryrun_dir)
     if not d.exists():
         print(f"# roofline: {d} missing (run repro.launch.dryrun first)",
@@ -1128,11 +1133,11 @@ def roofline(dryrun_dir: str = "experiments/dryrun/single_pod") -> None:
         if rec.get("status") != "ok":
             continue
         # per-device quantities (cost analysis of the SPMD module)
-        t_comp = rec["flops"] / PEAK_FLOPS
-        t_mem = rec["bytes_accessed"] / HBM_BW
+        t_comp = rec["flops"] / peaks.flops_bf16
+        t_mem = rec["bytes_accessed"] / peaks.hbm_bw
         coll = rec.get("collectives", {})
         coll_bytes = sum(v for k, v in coll.items() if k != "count")
-        t_coll = coll_bytes / ICI_BW
+        t_coll = coll_bytes / peaks.ici_link_bw
         dom = max((t_comp, "compute"), (t_mem, "memory"),
                   (t_coll, "collective"))[1]
         emit(f"roofline.{rec['arch']}.{rec['shape']}",
@@ -1161,7 +1166,9 @@ def main() -> None:
     # `--preemption {none,backlog,critical-boost}` composes with it and
     # runs the running-work displacement A/B instead.
     from repro.core import ALL_GATE_NAMES, ALL_PREEMPTION_NAMES
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     args = sys.argv[1:]
     selected: list[str] = []
     vehicle = "sim"

@@ -1,4 +1,4 @@
-"""Mixed-mode DAG on the threaded runtime with REAL Pallas-validated kernels.
+"""Mixed-mode DAG on the threaded runtime with real kernels.
 
 Each TAO executes actual JAX work matching its paper class:
   matmul -> blocked matrix multiply     (compute-bound)
@@ -26,9 +26,10 @@ MAT = jnp.asarray(RNG.standard_normal((256, 256)), jnp.float32)
 ROWS = jnp.asarray(RNG.standard_normal((32, 1024)), jnp.float32)
 STREAM = jnp.asarray(RNG.standard_normal((2048, 256)), jnp.float32)
 
-matmul_j = jax.jit(lambda x: ops.matmul(x, x, force="ref"))
-sort_j = jax.jit(lambda x: ops.sort_rows(x, force="ref"))
-copy_j = jax.jit(lambda x: ops.copy(x, force="ref"))
+# auto dispatch: the Pallas kernels on a TPU, their XLA references elsewhere
+matmul_j = jax.jit(lambda x: ops.matmul(x, x))
+sort_j = jax.jit(ops.sort_rows)
+copy_j = jax.jit(ops.copy)
 
 
 def bind_real_work(dag) -> None:

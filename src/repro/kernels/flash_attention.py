@@ -25,7 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
+from .matmul import f32_precision
+
 
 NEG_INF = -1e30
 
@@ -61,11 +62,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(run)
     def _block():
+        precision = f32_precision(q_ref.dtype)
         q = q_ref[0, 0].astype(jnp.float32)          # (bq, d)
         k = k_ref[0, 0].astype(jnp.float32)          # (bk, d)
         v = v_ref[0, 0].astype(jnp.float32)          # (bk, d)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((1,), (1,)), ((), ())), precision=precision,
             preferred_element_type=jnp.float32) * sm_scale   # (bq, bk)
         rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -83,7 +85,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         alpha = jnp.exp(m_prev - m_new)               # (bq, 1)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            p, v, (((1,), (0,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(ki == kv_steps - 1)
@@ -138,7 +141,7 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

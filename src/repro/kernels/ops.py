@@ -1,8 +1,10 @@
 """Public jit'd kernel entry points.
 
 Each op dispatches to the Pallas kernel on TPU and to the pure-jnp reference
-on other backends (this container is CPU-only; Pallas correctness is
-validated against the oracles in interpret mode by the test suite).  Setting
+(XLA) on other backends.  The test suite checks every kernel body against
+the oracles in ``ref.py`` in interpret mode on CPU, and
+``tests/test_tpu_compile.py`` compiles each kernel for a described TPU v5e;
+``chip_smoke.py`` runs them on a chip against ``ref.py``.  Setting
 ``force='pallas'``/``force='ref'`` overrides dispatch; ``force='interpret'``
 runs the Pallas kernel body in interpret mode (Python on CPU).
 
@@ -108,9 +110,10 @@ class KernelImpl:
     """One interchangeable implementation of the kernel library.
 
     ``force`` is the value the back-compat shim understands; ``available``
-    is the host predicate (evaluated at enumeration time, so a registry
-    consumer on a TPU host sees ``pallas`` while a CPU host sees
-    ``interpret`` only if the Pallas interpreter actually works there).
+    is the host predicate, evaluated at enumeration time: a TPU host sees
+    ``ref`` and ``pallas``, a CPU host sees ``ref`` and, where the Pallas
+    interpreter works there, ``interpret``.  Interpret mode is never a
+    variant on a TPU, where it would be a slow copy of ``pallas``.
     """
 
     name: str
@@ -143,7 +146,8 @@ def _interpret_works() -> bool:
 _IMPLS = (
     KernelImpl("ref", "ref", lambda: True),
     KernelImpl("pallas", "pallas", _pallas_native),
-    KernelImpl("interpret", "interpret", _interpret_works),
+    KernelImpl("interpret", "interpret",
+               lambda: not _pallas_native() and _interpret_works()),
 )
 
 

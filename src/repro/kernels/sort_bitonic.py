@@ -8,8 +8,11 @@ in VMEM between stages (the data-reuse property the paper selects sort for).
 
 Each grid step sorts ``block_rows`` independent rows of length ``n`` (a power
 of two).  A stage at (k, j) compare-exchanges lanes at distance d = 2^j with
-direction flipping every 2^(k+1) lanes; we express it with reshapes so it
-lowers to plain VPU min/max — no gathers.
+direction flipping every 2^(k+1) lanes.  The block keeps its 2-D
+``(block_rows, n)`` layout: the partner lanes come from two lane rotations
+and an iota mask picks min or max per lane, so a stage lowers to lane
+rotates plus VPU min/max/select — no gathers and no reshape of the lane
+axis (which Mosaic refuses).
 """
 from __future__ import annotations
 
@@ -21,25 +24,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 
 def _bitonic_stage(x: jax.Array, k: int, j: int) -> jax.Array:
-    """One compare-exchange stage on rows; x: (rows, n)."""
-    rows, n = x.shape
+    """One compare-exchange stage on rows; x: (rows, n).
+
+    Lane ``i`` pairs with lane ``i ^ d``: the lower lane of a pair reads
+    its partner from ``roll(x, n - d)`` (lane ``i + d``), the upper lane
+    from ``roll(x, d)`` (lane ``i - d``).  ``pltpu.roll`` shifts like
+    ``jnp.roll``; the lower lane keeps the min where the pair sorts
+    ascending, i.e. where bit (k+1) of the lane index is 0."""
+    n = x.shape[1]
     d = 1 << j
-    span = 1 << (k + 1)  # direction period
-    # group lanes as (groups, 2, d): pairs at distance d
-    g = x.reshape(rows, n // (2 * d), 2, d)
-    a, b = g[:, :, 0, :], g[:, :, 1, :]
-    lo = jnp.minimum(a, b)
-    hi = jnp.maximum(a, b)
-    # ascending iff bit (k+1) of the group's base lane index is 0
-    base = jnp.arange(n // (2 * d), dtype=jnp.int32) * (2 * d)
-    asc = ((base // span) % 2 == 0)[None, :, None]  # (1, groups, 1)
-    first = jnp.where(asc, lo, hi)
-    second = jnp.where(asc, hi, lo)
-    return jnp.stack([first, second], axis=2).reshape(rows, n)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    upper = (lane & d) != 0
+    partner = jnp.where(upper, pltpu.roll(x, d, 1), pltpu.roll(x, n - d, 1))
+    ascending = ((lane >> (k + 1)) & 1) == 0
+    return jnp.where(upper != ascending, jnp.minimum(x, partner),
+                     jnp.maximum(x, partner))
 
 
 def _sort_kernel(x_ref, o_ref, *, n: int):
@@ -65,7 +66,7 @@ def sort_rows(x: jax.Array, *, block_rows: int = 8, interpret: bool = False):
         in_specs=[pl.BlockSpec((block_rows, n), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

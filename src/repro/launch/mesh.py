@@ -1,13 +1,20 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-``make_production_mesh`` is a FUNCTION (not a module constant) so importing
-this module never touches jax device state — required because the dry-run
-must set ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any
-jax initialization, while smoke tests must see 1 device.
+``make_production_mesh`` is a FUNCTION (not a module constant), so importing
+this module never touches jax device state.  Its 256- and 512-chip meshes
+exist only in the dry run, which sets
+``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before jax
+initializes.  Everywhere else ``jax.devices()`` is what the host has: one
+CPU device under the tests, one chip (or the four of a v5e 2x2 host) on a
+TPU, and ``make_debug_mesh`` spans those.  The scheduler's threaded runtime
+uses no mesh: every worker launches onto the default device.
 """
 from __future__ import annotations
 
 import jax
+
+# the chip a production mesh stands for: a 16x16 v5e pod
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -1,22 +1,49 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Batched prefill+decode serving of a (smoke-sized) model, scheduled either
-directly or through the XiTAO runtime (``--orchestrate``), where the PTT +
-weight-based policy learn prefill->big / decode->LITTLE placement online.
+Batched prefill+decode serving of a model (full width, or smoke-sized with
+``--smoke``), scheduled either directly or through the XiTAO runtime
+(``--orchestrate``), where the PTT + weight-based policy learn
+prefill->big / decode->LITTLE placement online.  ``main`` returns a
+:class:`ServeReport` so an in-process caller (``chip_smoke.py``) can check
+what was served.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
 import jax.numpy as jnp
 
 from ..configs import ARCH_IDS, get_config, get_smoke_config
+from ..core.serve_orchestrator import ServeStats
 from ..models import get_model
+from .compile_cache import enable_compile_cache
 
 
-def main(argv=None) -> None:
+@dataclasses.dataclass
+class ServeReport:
+    """What one ``main`` call served.
+
+    Batch mode fills ``vocab_size``, ``params``, ``prompt`` (``(batch,
+    prompt_len)`` ids), ``tokens`` (``(batch, gen)`` generated ids) and
+    ``logits`` (the last decode step's), enough to recompute the last step
+    with the model's full forward pass.  ``--orchestrate`` and ``--zoo``
+    add the orchestrated run's ``stats`` and its ``requests``;
+    ``--orchestrate`` also ``n_taos``, the TAOs its requests build."""
+
+    vocab_size: int = 0
+    params: dict | None = None
+    prompt: jax.Array | None = None
+    tokens: jax.Array | None = None
+    logits: jax.Array | None = None
+    requests: list = dataclasses.field(default_factory=list)
+    n_taos: int = 0
+    stats: ServeStats | None = None
+
+
+def main(argv=None) -> ServeReport:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -28,10 +55,10 @@ def main(argv=None) -> None:
                     help="orchestrate a bursty two-tenant trace through the "
                          "tenant zoo instead of a single-model batch")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.zoo:
-        _run_zoo(args)
-        return
+        return _run_zoo(args)
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.family == "encoder":
@@ -67,14 +94,21 @@ def main(argv=None) -> None:
     print(f"prefill: {t_prefill:.3f}s "
           f"({args.batch * args.prompt_len / t_prefill:.0f} tok/s)")
     print(f"decode:  {t_decode:.3f}s ({total_tokens / t_decode:.0f} tok/s)")
+    report = ServeReport(vocab_size=cfg.vocab_size, params=params,
+                         prompt=toks,
+                         tokens=jnp.concatenate(out_tokens, axis=1),
+                         logits=logits)
 
     if args.orchestrate:
         from ..core import hikey960, make_policy
         from ..core.serve_orchestrator import (ServeRequest,
+                                               build_serving_dag,
                                                run_serving_threaded)
         reqs = [ServeRequest(i, args.prompt_len, args.gen)
                 for i in range(args.batch * 4)]
-        stats = run_serving_threaded(
+        report.requests = reqs
+        report.n_taos = len(build_serving_dag(reqs).nodes)
+        report.stats = stats = run_serving_threaded(
             reqs, hikey960(), make_policy("molding:weight"),
             prefill_fn=lambda r: jax.block_until_ready(
                 prefill_j(params, {"tokens": toks})[0]),
@@ -84,9 +118,10 @@ def main(argv=None) -> None:
               f"{stats.tokens_per_s:.0f} tok/s, "
               f"mean sojourn {stats.mean_latency * 1e3:.1f} ms, "
               f"p99 {stats.p99_latency * 1e3:.1f} ms")
+    return report
 
 
-def _run_zoo(args) -> None:
+def _run_zoo(args) -> ServeReport:
     """Bursty two-tenant trace through the tenant zoo on real threads."""
     from ..core import hikey960, make_policy
     from ..core.admission import make_gate
@@ -117,6 +152,7 @@ def _run_zoo(args) -> None:
             fastest = min(cells.values())
             print(f"  PTT[{typ}]: {len(cells)} measured cells, "
                   f"fastest {fastest * 1e3:.2f} ms")
+    return ServeReport(requests=reqs, stats=stats)
 
 
 if __name__ == "__main__":

@@ -20,6 +20,7 @@ from ..data import SyntheticLM
 from ..models import get_model, make_train_step
 from ..optimizer import adamw_init, cosine_schedule
 from ..parallel.sharding import use_sharding
+from .compile_cache import enable_compile_cache
 from .mesh import make_debug_mesh
 
 
@@ -39,6 +40,7 @@ def main(argv=None) -> None:
                     help="run microbatches through the XiTAO scheduler")
     ap.add_argument("--microbatches", type=int, default=2)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = get_model(cfg)

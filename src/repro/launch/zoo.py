@@ -63,8 +63,9 @@ class ZooTenant:
         self.slab_tokens = max(1, int(slab_tokens))
         self.decode_steps = max(1, int(decode_steps))
         # multi_impl: bind every host-available kernel implementation
-        # (ops.available_impls()) as TAO variants, so the scheduler picks
-        # the impl jointly with (leader, width).  Kernel flavor only — the
+        # (ops.available_impls(): ref + pallas on a TPU, ref + interpret on
+        # CPU) as TAO variants, so the scheduler picks the impl jointly
+        # with (leader, width).  Kernel flavor only — the
         # model flavors run jitted whole-model payloads with no variant
         # axis.  Off by default: single-variant tenants schedule
         # byte-identically to the pre-variant zoo.
@@ -97,13 +98,14 @@ class ZooTenant:
             return prefill_slab
 
         def make_decode(copy_op) -> Callable[[], None]:
-            # the burst's GEMV is a single row — below the Pallas matmul's
-            # tile granularity — so a variant only swaps the copy kernel (the
-            # class-defining op) and the GEMV stays on auto dispatch
+            # the burst's GEMV is a single row, below the Pallas matmul's
+            # 128-row tile, so it is pinned to XLA (force="ref") on every
+            # backend; a variant only swaps the copy kernel (the
+            # class-defining op)
             def decode_burst() -> None:
                 for _ in range(self.decode_steps):
                     moved = copy_op(cache_slab)
-                    y = ops.matmul(x1, w)
+                    y = ops.matmul(x1, w, force="ref")
                     jax.block_until_ready((moved, y))
             return decode_burst
 
