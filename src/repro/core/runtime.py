@@ -68,13 +68,27 @@ every enqueue/distribute (epoch counter closes the missed-wakeup race) —
 parking changes *when* a worker rescans, never what it may legally pop, so
 schedules remain valid interleavings of the same DPA state machine the
 simulator executes deterministically.
+
+Tracing: a run started while the JAX profiler records writes host spans
+(``jax.profiler.TraceAnnotation``) named ``repro.runtime.<site>`` onto the
+profiler's own clock — ``run``, ``spawn``, ``join``, ``admit_dag``,
+``admit``, ``place``, ``chunk``, ``commit`` and ``park`` — each carrying the
+ids of what it works on (``dag_id``, ``tao_id``, ``chunk``, ``worker``).
+The same runs stamp ``TraceRecord.ready`` and return per-site host time
+(``WorkloadResult.host_ns``) and counts (``WorkloadResult.counts``), kept
+per thread so that the hot path takes no lock.  ``_begin_run`` asks the
+profiler once per run; when it is off, each site costs one bool test and
+neither a span object nor a clock read.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import heapq
 import itertools
+import math
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -97,6 +111,38 @@ class ChunkedWork:
     n_chunks: int = 1
 
 
+def _profiler_span():
+    """``jax.profiler.TraceAnnotation`` while the JAX profiler records, else
+    None.  A process that never imported JAX cannot be tracing, so the
+    scheduler does not import it."""
+    if "jax" not in sys.modules:
+        return None
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation if TraceAnnotation.is_enabled() else None
+
+
+class _Tally:
+    """Host time and counts of one thread during a traced run.  Only that
+    thread writes its tally (workers index theirs by worker id), so no lock
+    guards it; the run sums them at its end."""
+
+    COUNTS = ("admits", "places", "commits", "chunks", "steal_attempts",
+              "steals", "parks", "park_timeouts")
+    __slots__ = ("admit_ns", "place_ns", "commit_ns") + COUNTS
+
+    def __init__(self):
+        for k in self.__slots__:
+            setattr(self, k, 0)
+
+    @classmethod
+    def totals(cls, tallies) -> tuple[dict, dict]:
+        """-> (self ns per site, summed counts) over ``tallies``."""
+        host_ns = {site: sum(getattr(t, site + "_ns") for t in tallies)
+                   for site in ("admit", "place", "commit")}
+        return host_ns, {k: sum(getattr(t, k) for t in tallies)
+                         for k in cls.COUNTS}
+
+
 class _TaoExec:
     """Per-segment state of a TAO execution (membership, timing).
 
@@ -106,7 +152,7 @@ class _TaoExec:
 
     __slots__ = ("tao", "leader", "width", "members", "cursor",
                  "start_claims", "remaining_members", "start_time", "lock",
-                 "leader_start")
+                 "leader_start", "ready")
 
     def __init__(self, tao: TAO, leader: int, width: int, n_workers: int,
                  dead=(), popper: int | None = None, members=None):
@@ -129,6 +175,7 @@ class _TaoExec:
         self.remaining_members = len(self.members)
         self.start_time = 0.0
         self.leader_start = 0.0
+        self.ready = math.nan     # entered the ready deque (traced runs)
         self.lock = threading.Lock()
 
 
@@ -206,6 +253,12 @@ class ThreadedRuntime:
         self._speed_scale = [1.0] * n          # DEGRADE sleep-scaling
         self._chaos = None                     # active ChaosPlan or None
         self._scratch: bytearray | None = None  # measured-transfer buffer
+        # tracing state, set per run by _begin_run (see the module doc)
+        self._span = None                      # TraceAnnotation or None
+        self._tracing = False
+        self._run_span = None                  # the open repro.runtime.run
+        self._tallies: list[_Tally] = []       # workers, then 2 more threads
+        self._ready_at: dict[TAO, float] = {}  # TAO -> ready stamp (rel.)
 
     # ------------------------------------------------------------------ admin
     def _begin_run(self, total: int) -> None:
@@ -248,7 +301,26 @@ class ThreadedRuntime:
         for q in self._assembly:
             q.clear()
         self._qlen = [0] * (self.n_shards or 1)
+        # the profiler is asked once per run: the hot path tests this bool
+        self._span = _profiler_span()
+        self._tracing = self._span is not None
+        self._ready_at = {}
+        self._tallies = []
+        if self._tracing:
+            # one tally per worker, one for the caller / admitter thread
+            # (slot n) and one for the chaos injector (slot n + 1)
+            self._tallies = [_Tally() for _ in range(self.spec.n_workers + 2)]
+            self._run_span = self._span("repro.runtime.run")
+            self._run_span.__enter__()
+        # stamped just inside the run span: a TraceRecord time t maps to
+        # that span's start + t on the profiler's clock
         self._t0 = time.perf_counter()
+
+    def _end_run(self) -> None:
+        """Close the run span ``_begin_run`` opened, if any."""
+        if self._run_span is not None:
+            self._run_span.__exit__(None, None, None)
+            self._run_span = None
 
     def _signal_work(self) -> None:
         """New work (or shutdown) exists: wake parked workers.
@@ -267,7 +339,23 @@ class ThreadedRuntime:
         self._done.set()
         self._signal_work()
 
-    def _enqueue_ready(self, tao: TAO, waker: int) -> None:
+    def _enqueue_ready(self, tao: TAO, waker: int,
+                       slot: int | None = None) -> None:
+        """Admit a ready TAO and push it onto a ready deque.  ``slot`` is
+        the calling thread's tally in a traced run: ``waker`` (a worker's
+        own) unless a non-worker thread enqueues."""
+        if not self._tracing:
+            self._admit_ready(tao, waker, None)
+            return
+        tally = self._tallies[waker if slot is None else slot]
+        t_in = time.perf_counter_ns()
+        with self._span("repro.runtime.admit", dag_id=tao.dag_id,
+                        tao_id=tao.id, worker=waker):
+            self._admit_ready(tao, waker, self._ready_at)
+        tally.admit_ns += time.perf_counter_ns() - t_in
+        tally.admits += 1
+
+    def _admit_ready(self, tao: TAO, waker: int, ready_at) -> None:
         placement = self.core.admit(tao, waker)
         target = placement.target
         dead = self._dead_workers
@@ -282,6 +370,9 @@ class ThreadedRuntime:
                     target = c
                     break
         with self._qlocks[target]:
+            if ready_at is not None:
+                # stamped before the push: no worker can place it earlier
+                ready_at[tao] = time.perf_counter() - self._t0
             self._ready[target].append(tao)
         if self.n_shards is not None:
             s = self.core.shard_of_worker[target]
@@ -379,7 +470,7 @@ class ThreadedRuntime:
                     tao.id, tao.type, ex.leader, ex.width,
                     ex.start_time - self._t0, now_rel, tuple(ex.members),
                     dag_id=tao.dag_id, preempted=True,
-                    impl=tao.assigned_impl))
+                    impl=tao.assigned_impl, ready=ex.ready))
                 st = self._wl_stats.get(tao.dag_id)
                 if st is not None:
                     st.record_preemption()
@@ -401,7 +492,7 @@ class ThreadedRuntime:
                     tao.id, tao.type, ex.leader, ex.width,
                     ex.start_time - self._t0, now_rel, tuple(ex.members),
                     dag_id=tao.dag_id, preempted=True,
-                    impl=tao.assigned_impl))
+                    impl=tao.assigned_impl, ready=ex.ready))
                 st = self._wl_stats.get(tao.dag_id)
                 if st is not None:
                     st.record_failure_requeue()
@@ -429,6 +520,18 @@ class ThreadedRuntime:
 
     def _dpa_distribute(self, tao: TAO, popper: int) -> None:
         """Dynamic Place Allocation: push into members' assembly queues."""
+        if not self._tracing:
+            self._place(tao, popper, None)
+            return
+        tally = self._tallies[popper]
+        t_in = time.perf_counter_ns()
+        with self._span("repro.runtime.place", dag_id=tao.dag_id,
+                        tao_id=tao.id, worker=popper):
+            self._place(tao, popper, self._ready_at)
+        tally.place_ns += time.perf_counter_ns() - t_in
+        tally.places += 1
+
+    def _place(self, tao: TAO, popper: int, ready_at) -> None:
         width = tao.assigned_width
         # sharded cores fold the place into the popper's shard (a place
         # never spans shards); unsharded this is exactly leader_of()
@@ -467,6 +570,8 @@ class ThreadedRuntime:
                       dead=tuple(self._dead_workers), popper=popper,
                       members=self.core.members_for(leader, width))
         ex.start_time = time.perf_counter()
+        if ready_at is not None:
+            ex.ready = ready_at.pop(tao, math.nan)
         if self._preempt is not None:
             with self._run_lock:
                 self._running_execs[tao] = ex
@@ -505,6 +610,7 @@ class ThreadedRuntime:
             ex.leader_start = time.perf_counter()
         dead = self._dead_workers
         chaos = self._chaos is not None
+        tracing = self._tracing
         while True:
             # death point: a killed worker refuses further claims (its
             # in-flight chunk — claimed before the kill landed — already
@@ -516,14 +622,16 @@ class ThreadedRuntime:
             i = cursor.claim()
             if i is None:
                 break
-            if chaos:
-                # DEGRADE sleep-scaling: a chunk that took dt at full speed
-                # takes dt/s on a worker degraded to speed s
-                t_c = time.perf_counter()
-                work.chunk_fn(i)
-                s = self._speed_scale[worker]
-                if s < 1.0:
-                    time.sleep((time.perf_counter() - t_c) * (1.0 / s - 1.0))
+            if tracing:
+                self._tallies[worker].chunks += 1
+                with self._span("repro.runtime.chunk", dag_id=ex.tao.dag_id,
+                                tao_id=ex.tao.id, chunk=i, worker=worker):
+                    if chaos:
+                        self._degraded_chunk(work, i, worker)
+                    else:
+                        work.chunk_fn(i)
+            elif chaos:
+                self._degraded_chunk(work, i, worker)
             else:
                 work.chunk_fn(i)
         # Snapshot the yield state BEFORE the member-exit decrement: once
@@ -537,42 +645,76 @@ class ThreadedRuntime:
         with ex.lock:
             ex.remaining_members -= 1
             last = ex.remaining_members == 0
-        if is_leader and not preempted and not (dead and worker in dead):
-            # leader-only PTT record; a preempted segment's elapsed covers
-            # partial work mid-displacement and is skipped.  A
-            # continuation's completing segment records as-is: it
-            # understates a full TAO, but dropping it starves the model
-            # and scaling by the chunk ratio destabilized placement
-            # learning (see the simulator's matching comment) — the bias
-            # is marginal (continuations are rare, capped by
-            # max_preemptions) and policies' ratio signals are unbiased.
-            elapsed = time.perf_counter() - ex.leader_start
-            self.core.record_time(ex.tao, ex.leader, ex.width, max(elapsed, 1e-9))
-        if last:
-            if self._preempt is not None:
-                with self._run_lock:
-                    if self._running_execs.pop(ex.tao, None) is not None:
-                        self._occupied_slots -= len(ex.members)
-            if cursor.unclaimed > 0:
-                # chunks left with nobody claiming them: either a controller
-                # yielded the TAO, or every remaining claimer died.  Both
-                # repackage the unclaimed chunks as a continuation through
-                # release->admit; only the policy displacement spends the
-                # preemption budget and feeds damping.
-                if cursor.yield_requested:
-                    self._requeue_preempted(ex, worker)
-                else:
-                    self._requeue_failed(ex, worker)
-                return
+        # leader-only PTT record; a preempted segment's elapsed covers
+        # partial work mid-displacement and is skipped.  A continuation's
+        # completing segment records as-is: it understates a full TAO, but
+        # dropping it starves the model and scaling by the chunk ratio
+        # destabilized placement learning (see the simulator's matching
+        # comment) — the bias is marginal (continuations are rare, capped
+        # by max_preemptions) and policies' ratio signals are unbiased.
+        record = is_leader and not preempted and not (dead and worker in dead)
+        if not last:
+            if record:
+                self._record_leader_time(ex)
+            return
+        if not tracing:
+            self._last_member_exit(ex, worker, record)
+            return
+        tally = self._tallies[worker]
+        t_in, admit_in = time.perf_counter_ns(), tally.admit_ns
+        with self._span("repro.runtime.commit", dag_id=ex.tao.dag_id,
+                        tao_id=ex.tao.id, worker=worker):
+            committed = self._last_member_exit(ex, worker, record)
+        # self time: the children's admits nested here count as admits
+        tally.commit_ns += (time.perf_counter_ns() - t_in
+                            - (tally.admit_ns - admit_in))
+        tally.commits += committed
+
+    def _degraded_chunk(self, work: ChunkedWork, i: int, worker: int) -> None:
+        """DEGRADE sleep-scaling: a chunk that took dt at full speed takes
+        dt/s on a worker degraded to speed s."""
+        t_c = time.perf_counter()
+        work.chunk_fn(i)
+        s = self._speed_scale[worker]
+        if s < 1.0:
+            time.sleep((time.perf_counter() - t_c) * (1.0 / s - 1.0))
+
+    def _record_leader_time(self, ex: _TaoExec) -> None:
+        elapsed = time.perf_counter() - ex.leader_start
+        self.core.record_time(ex.tao, ex.leader, ex.width, max(elapsed, 1e-9))
+
+    def _last_member_exit(self, ex: _TaoExec, worker: int,
+                          record: bool) -> bool:
+        """The last member leaves: commit-and-wakeup, or requeue the
+        unclaimed chunks as a continuation.  Returns True if it committed."""
+        if record:
+            self._record_leader_time(ex)
+        cursor = ex.cursor
+        if self._preempt is not None:
+            with self._run_lock:
+                if self._running_execs.pop(ex.tao, None) is not None:
+                    self._occupied_slots -= len(ex.members)
+        if cursor.unclaimed > 0:
+            # chunks left with nobody claiming them: either a controller
+            # yielded the TAO, or every remaining claimer died.  Both
+            # repackage the unclaimed chunks as a continuation through
+            # release->admit; only the policy displacement spends the
+            # preemption budget and feeds damping.
             if cursor.yield_requested:
-                cursor.clear_yield()   # yield raced with the final claim
-            end_rel = time.perf_counter() - self._t0
-            for child in self.core.commit_and_wakeup(ex.tao):
-                self._enqueue_ready(child, waker=worker)
-            if self._wl_stats is not None:
-                self._record_completion(ex, end_rel)
-            if self.core.completed >= self._total:
-                self._set_done()
+                self._requeue_preempted(ex, worker)
+            else:
+                self._requeue_failed(ex, worker)
+            return False
+        if cursor.yield_requested:
+            cursor.clear_yield()   # yield raced with the final claim
+        end_rel = time.perf_counter() - self._t0
+        for child in self.core.commit_and_wakeup(ex.tao):
+            self._enqueue_ready(child, waker=worker)
+        if self._wl_stats is not None:
+            self._record_completion(ex, end_rel)
+        if self.core.completed >= self._total:
+            self._set_done()
+        return True
 
     def _record_completion(self, ex: _TaoExec, end_rel: float) -> None:
         """Workload-mode accounting: per-DAG table + trace record."""
@@ -582,7 +724,7 @@ class ThreadedRuntime:
             self._trace.append(TraceRecord(
                 tao.id, tao.type, ex.leader, ex.width,
                 ex.start_time - self._t0, end_rel, tuple(ex.members),
-                dag_id=tao.dag_id, impl=tao.assigned_impl))
+                dag_id=tao.dag_id, impl=tao.assigned_impl, ready=ex.ready))
             st = self._wl_stats.get(tao.dag_id)
             if st is not None:
                 st.record_completion(end_rel)
@@ -687,6 +829,7 @@ class ThreadedRuntime:
     def _worker_loop(self, worker: int) -> None:
         rng = self._rngs[worker]
         n = self.spec.n_workers
+        tracing = self._tracing
         try:
             while not self._done.is_set():
                 # epoch read precedes the queue scans (see _signal_work)
@@ -707,7 +850,9 @@ class ThreadedRuntime:
                     #    only on threshold imbalance (see _steal_once).
                     #    (Stealing FROM a dead worker's deque is allowed:
                     #    it rescues anything stranded there.)
-                    if n > 1 and self._steal_once(worker, rng):
+                    if n > 1 and (
+                            self._counted_steal(worker, rng) if tracing
+                            else self._steal_once(worker, rng)):
                         continue
                 # 4) nothing anywhere: park until new work is signalled.
                 #    On wake-up the loop re-runs the local checks before the
@@ -716,29 +861,50 @@ class ThreadedRuntime:
                 with self._work_cv:
                     if self._work_epoch == epoch and not self._done.is_set():
                         self._n_parked += 1
-                        self._work_cv.wait(timeout=self.park_timeout_s)
+                        if tracing:
+                            self._traced_park(worker)
+                        else:
+                            self._work_cv.wait(timeout=self.park_timeout_s)
                         self._n_parked -= 1
         except BaseException as e:  # surface worker crashes to run()
             self._error = e
             self._set_done()
+
+    def _counted_steal(self, worker: int, rng) -> bool:
+        tally = self._tallies[worker]
+        stolen = self._steal_once(worker, rng)
+        tally.steal_attempts += 1
+        tally.steals += stolen
+        return stolen
+
+    def _traced_park(self, worker: int) -> None:
+        """The park wait (under ``_work_cv``), spanned and counted."""
+        tally = self._tallies[worker]
+        with self._span("repro.runtime.park", worker=worker):
+            notified = self._work_cv.wait(timeout=self.park_timeout_s)
+        tally.parks += 1
+        tally.park_timeouts += not notified
 
     # ------------------------------------------------------------------ run
     def _run_workers(self, timeout_s: float) -> float:
         """Spawn the worker pool, wait for completion, join, re-raise.
 
         Returns the elapsed wall-clock since ``_begin_run`` set ``_t0``."""
-        threads = [
-            threading.Thread(target=self._worker_loop, args=(i,), daemon=True)
-            for i in range(self.spec.n_workers)
-        ]
-        self._threads = threads
-        for t in threads:
-            t.start()
+        with self._run_phase_span("repro.runtime.spawn"):
+            threads = [
+                threading.Thread(target=self._worker_loop, args=(i,),
+                                 daemon=True)
+                for i in range(self.spec.n_workers)
+            ]
+            self._threads = threads
+            for t in threads:
+                t.start()
         finished = self._done.wait(timeout=timeout_s)
         elapsed = time.perf_counter() - self._t0
-        self._set_done()
-        for t in threads:
-            t.join(timeout=5.0)
+        with self._run_phase_span("repro.runtime.join"):
+            self._set_done()
+            for t in threads:
+                t.join(timeout=5.0)
         if self._error is not None:
             raise self._error
         if not finished:
@@ -747,13 +913,22 @@ class ThreadedRuntime:
                 f"({self.core.completed}/{self._total} TAOs)")
         return elapsed
 
+    def _run_phase_span(self, name: str, **ids):
+        """A span around once-per-run work (not the hot path)."""
+        if self._tracing:
+            return self._span(name, **ids)
+        return contextlib.nullcontext()
+
     def run(self, dag: TaoDag, timeout_s: float = 600.0) -> dict:
         """Execute one DAG offline (all roots ready at t=0)."""
         self._begin_run(len(dag))
-        roots = self.core.prepare(dag)
-        for r in roots:
-            self._enqueue_ready(r, waker=0)
-        elapsed = self._run_workers(timeout_s)
+        try:
+            roots = self.core.prepare(dag)
+            for r in roots:
+                self._enqueue_ready(r, waker=0, slot=self.spec.n_workers)
+            elapsed = self._run_workers(timeout_s)
+        finally:
+            self._end_run()
         return {
             "elapsed_s": elapsed,
             "throughput_taos_per_s": self._total / elapsed if elapsed > 0 else 0.0,
@@ -771,7 +946,6 @@ class ThreadedRuntime:
         (token-bucket) decides identically on both vehicles.  REJECT
         verdicts mark the DAG's stats row and shrink the completion target.
         """
-        from .admission import DELAY, REJECT, AdmissionRequest
         pending = [(arr.at, i, arr, None) for i, arr in enumerate(arrivals)]
         heapq.heapify(pending)
         seq = itertools.count(len(arrivals))
@@ -786,63 +960,70 @@ class ThreadedRuntime:
                 if self._done.is_set():
                     return
                 _, _, arr, req = heapq.heappop(pending)
-                now = time.perf_counter() - self._t0
-                if req is not None and id(req) in counted:
-                    counted.discard(id(req))
-                    with self._stats_lock:
-                        self._throttled_ns[req.tenant] -= 1
-                if gate is not None:
-                    if req is None:
-                        req = AdmissionRequest(
-                            dag_id=arr.dag_id, tenant=arr.tenant,
-                            n_taos=len(arr.dag), arrival=arr.at)
-                    verdict = gate.decide(req, now,
-                                          self.core.admission_signals())
-                    if verdict.action == DELAY:
-                        req.attempts += 1
-                        if verdict.dominant:
-                            counted.add(id(req))
-                            with self._stats_lock:
-                                self._throttled_ns[req.tenant] = \
-                                    self._throttled_ns.get(req.tenant, 0) + 1
-                        # preemption consult point 2 (gate feedback): the
-                        # gate throttled this tenant *for dominating the
-                        # backlog* — displace its in-flight work too (a
-                        # tenant delayed for its own degraded p99 is a
-                        # victim, not a cause, and is never forwarded)
-                        if self._preempt is not None and verdict.dominant:
-                            self._yield_victims(self._preempt.on_gate_feedback(
-                                req.tenant, self._running_views(),
-                                self.core.admission_signals(),
-                                self._tenant_backlog()))
-                        # strictly-future retry so a zero-quantum gate
-                        # cannot spin this thread
-                        retry = max(verdict.retry_at, now + 1e-4)
-                        heapq.heappush(pending,
-                                       (retry, next(seq), arr, req))
-                        continue
-                    if verdict.action == REJECT:
-                        with self._stats_lock:
-                            self._wl_stats[arr.dag_id].mark_rejected()
-                        gate.on_reject(req, now)
-                        self._discount_total(len(arr.dag))
-                        continue
-                    gate.on_admit(req, now)
-                with self._stats_lock:
-                    self._wl_stats[arr.dag_id].mark_admitted(now)
-                    self._backlog_ns[arr.tenant] = \
-                        self._backlog_ns.get(arr.tenant, 0) + len(arr.dag)
-                # deferred payload binding: materialize real ChunkedWork
-                # closures only for DAGs that actually got in (rejected
-                # arrivals never reach this point, so never pay for them)
-                if arr.bind is not None:
-                    arr.bind(arr.dag)
-                roots = self.core.prepare(arr.dag, dag_id=arr.dag_id)
-                for r in roots:
-                    self._enqueue_ready(r, waker=0)
+                with self._run_phase_span("repro.runtime.admit_dag",
+                                          dag_id=arr.dag_id):
+                    self._present(arr, req, gate, pending, seq, counted)
         except BaseException as e:  # surface admission crashes to run_workload
             self._error = e
             self._set_done()
+
+    def _present(self, arr, req, gate, pending: list, seq,
+                 counted: set) -> None:
+        """One arrival at the door: the gate's verdict, then (if admitted)
+        the payload binding, ``prepare`` and the root enqueues."""
+        from .admission import DELAY, REJECT, AdmissionRequest
+        now = time.perf_counter() - self._t0
+        if req is not None and id(req) in counted:
+            counted.discard(id(req))
+            with self._stats_lock:
+                self._throttled_ns[req.tenant] -= 1
+        if gate is not None:
+            if req is None:
+                req = AdmissionRequest(
+                    dag_id=arr.dag_id, tenant=arr.tenant,
+                    n_taos=len(arr.dag), arrival=arr.at)
+            verdict = gate.decide(req, now, self.core.admission_signals())
+            if verdict.action == DELAY:
+                req.attempts += 1
+                if verdict.dominant:
+                    counted.add(id(req))
+                    with self._stats_lock:
+                        self._throttled_ns[req.tenant] = \
+                            self._throttled_ns.get(req.tenant, 0) + 1
+                # preemption consult point 2 (gate feedback): the gate
+                # throttled this tenant *for dominating the backlog* —
+                # displace its in-flight work too (a tenant delayed for its
+                # own degraded p99 is a victim, not a cause, and is never
+                # forwarded)
+                if self._preempt is not None and verdict.dominant:
+                    self._yield_victims(self._preempt.on_gate_feedback(
+                        req.tenant, self._running_views(),
+                        self.core.admission_signals(),
+                        self._tenant_backlog()))
+                # strictly-future retry so a zero-quantum gate cannot spin
+                # this thread
+                retry = max(verdict.retry_at, now + 1e-4)
+                heapq.heappush(pending, (retry, next(seq), arr, req))
+                return
+            if verdict.action == REJECT:
+                with self._stats_lock:
+                    self._wl_stats[arr.dag_id].mark_rejected()
+                gate.on_reject(req, now)
+                self._discount_total(len(arr.dag))
+                return
+            gate.on_admit(req, now)
+        with self._stats_lock:
+            self._wl_stats[arr.dag_id].mark_admitted(now)
+            self._backlog_ns[arr.tenant] = \
+                self._backlog_ns.get(arr.tenant, 0) + len(arr.dag)
+        # deferred payload binding: materialize real ChunkedWork closures
+        # only for DAGs that actually got in (rejected arrivals never reach
+        # this point, so never pay for them)
+        if arr.bind is not None:
+            arr.bind(arr.dag)
+        roots = self.core.prepare(arr.dag, dag_id=arr.dag_id)
+        for r in roots:
+            self._enqueue_ready(r, waker=0, slot=self.spec.n_workers)
 
     def _inject_chaos(self, plan) -> None:
         """Injector thread: apply each :class:`~repro.core.chaos.ChaosEvent`
@@ -893,7 +1074,7 @@ class ThreadedRuntime:
                                     if st is not None:
                                         st.record_failure_requeue()
                             self.core.release(tao, count_displacement=False)
-                            self._enqueue_ready(tao, waker=w)
+                            self._enqueue_ready(tao, waker=w, slot=n + 1)
                     self._signal_work()   # dead workers wake to drain
                     continue
                 # RECOVER: clear both kill and degrade state
@@ -928,11 +1109,19 @@ class ThreadedRuntime:
         default — never displaces and schedules exactly as before).
         ``chaos`` is an optional :class:`~repro.core.chaos.ChaosPlan`
         applied by an injector thread at wall-clock offsets (``None``
-        or an empty plan injects nothing and schedules as before)."""
-        from .workload import DagStats, WorkloadResult
+        or an empty plan injects nothing and schedules as before).
+        A run made while the JAX profiler records also fills the result's
+        ``host_ns`` and ``counts`` (see the module doc)."""
         arrivals = workload.arrivals()
-        total = workload.total_taos()
-        self._begin_run(total)
+        self._begin_run(workload.total_taos())
+        try:
+            return self._run_arrivals(arrivals, timeout_s, admission,
+                                      preemption, chaos)
+        finally:
+            self._end_run()
+
+    def _run_arrivals(self, arrivals, timeout_s, admission, preemption, chaos):
+        from .workload import DagStats, WorkloadResult
         self._gate = admission
         if chaos:
             self._chaos = chaos
@@ -983,4 +1172,6 @@ class ThreadedRuntime:
         )
         if self.n_shards is not None:
             result.exchanges = self.core.exchange_stats()
+        if self._tracing:
+            result.host_ns, result.counts = _Tally.totals(self._tallies)
         return result

@@ -161,6 +161,10 @@ class TraceRecord:
     # implementation variant the segment executed under (DEFAULT_IMPL for
     # legacy single-variant TAOs)
     impl: str = DEFAULT_IMPL
+    # when the segment entered a ready deque, on the run's clock: stamped
+    # by the threaded runtime while the JAX profiler records, NaN otherwise
+    # (and on the simulator); not part of equality or the trace signature
+    ready: float = dataclasses.field(default=math.nan, compare=False)
 
 
 @dataclasses.dataclass

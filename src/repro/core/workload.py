@@ -337,6 +337,13 @@ class WorkloadResult(SimResult):
     # (``ShardedScheduler.exchange_stats()``) — total/in/out per shard and
     # the peak imbalance seen at an exchange; None on unsharded runs
     exchanges: dict | None = None
+    # threaded runs made while the JAX profiler records, else empty: self
+    # nanoseconds of host time per runtime site ("admit", "place",
+    # "commit"; a commit's nested child admits count as admits), and the
+    # counts of admits, places, commits, chunks, steal_attempts, steals,
+    # parks and park_timeouts (parks that ended without a notify)
+    host_ns: dict = dataclasses.field(default_factory=dict)
+    counts: dict = dataclasses.field(default_factory=dict)
 
     def sojourns(self) -> list[float]:
         return [s.sojourn for s in self.per_dag.values() if s.done]

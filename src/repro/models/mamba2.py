@@ -177,28 +177,34 @@ class Mamba2LM(BaseModel):
         cfg = self.cfg
         B, S, M = x.shape
         DI, N, H, P = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
-        h = rmsnorm(x, p["norm.w"], cfg.norm_eps)
-        proj = h @ p["in_proj.w"].astype(h.dtype)
-        z, xs, b, c, dt = self._split(proj)
-        # depthwise causal conv over (xs|b|c)
-        xbc = jnp.concatenate([xs, b, c], axis=-1)       # (B,S,conv_dim)
-        w = p["conv.w"].astype(xbc.dtype)                # (K, conv_dim)
-        K = w.shape[0]
-        pad = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
-        conv = sum(pad[:, i:i + S] * w[i][None, None] for i in range(K))
-        conv = jax.nn.silu(conv + p["conv.b"].astype(conv.dtype))
-        xs, b, c = conv[..., :DI], conv[..., DI:DI + N], conv[..., DI + N:]
-        dt = jax.nn.softplus(dt.astype(jnp.float32) +
-                             p["dt_bias"].astype(jnp.float32))
-        y, final_state = ssd_chunked(
-            xs.reshape(B, S, H, P), dt, p["a_log"], b, c,
-            p["d_skip"], chunk=min(cfg.ssm_chunk, S),
-            shard_acts=cfg.ssd_shard_acts)
-        y = y.reshape(B, S, DI) * jax.nn.silu(z.astype(jnp.float32)
-                                              ).astype(y.dtype)
-        y = rmsnorm(y, p["gate_norm.w"], cfg.norm_eps)
-        y = constrain(y, "batch", "seq", "act_ff")
-        out = x + (y @ p["out_proj.w"].astype(y.dtype))
+        with jax.named_scope("norm"):
+            h = rmsnorm(x, p["norm.w"], cfg.norm_eps)
+        with jax.named_scope("in_proj"):
+            proj = h @ p["in_proj.w"].astype(h.dtype)
+            z, xs, b, c, dt = self._split(proj)
+        with jax.named_scope("conv"):
+            # depthwise causal conv over (xs|b|c)
+            xbc = jnp.concatenate([xs, b, c], axis=-1)   # (B,S,conv_dim)
+            w = p["conv.w"].astype(xbc.dtype)            # (K, conv_dim)
+            K = w.shape[0]
+            pad = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
+            conv = sum(pad[:, i:i + S] * w[i][None, None] for i in range(K))
+            conv = jax.nn.silu(conv + p["conv.b"].astype(conv.dtype))
+            xs, b, c = (conv[..., :DI], conv[..., DI:DI + N],
+                        conv[..., DI + N:])
+        with jax.named_scope("ssd"):
+            dt = jax.nn.softplus(dt.astype(jnp.float32) +
+                                 p["dt_bias"].astype(jnp.float32))
+            y, final_state = ssd_chunked(
+                xs.reshape(B, S, H, P), dt, p["a_log"], b, c,
+                p["d_skip"], chunk=min(cfg.ssm_chunk, S),
+                shard_acts=cfg.ssd_shard_acts)
+        with jax.named_scope("out_proj"):      # gate, gate norm, projection
+            y = y.reshape(B, S, DI) * jax.nn.silu(z.astype(jnp.float32)
+                                                  ).astype(y.dtype)
+            y = rmsnorm(y, p["gate_norm.w"], cfg.norm_eps)
+            y = constrain(y, "batch", "seq", "act_ff")
+            out = x + (y @ p["out_proj.w"].astype(y.dtype))
         if not want_state:
             return out, None
         conv_state = xbc[:, -(cfg.ssm_conv - 1):]
@@ -222,8 +228,9 @@ class Mamba2LM(BaseModel):
             return out, None
 
         x, _ = jax.lax.scan(body, x, stacked)
-        x = rmsnorm(x, params["final_norm.w"], cfg.norm_eps)
-        logits = x @ params["head.w"].astype(x.dtype)
+        with jax.named_scope("head"):
+            x = rmsnorm(x, params["final_norm.w"], cfg.norm_eps)
+            logits = x @ params["head.w"].astype(x.dtype)
         return constrain(logits, "batch", "seq", "vocab")
 
     def loss(self, params, batch):
@@ -271,8 +278,9 @@ class Mamba2LM(BaseModel):
             return out, state
 
         x, (conv_states, ssd_states) = jax.lax.scan(body, x, stacked)
-        x = rmsnorm(x, params["final_norm.w"], cfg.norm_eps)
-        logits = x[:, -1:] @ params["head.w"].astype(x.dtype)
+        with jax.named_scope("head"):
+            x = rmsnorm(x, params["final_norm.w"], cfg.norm_eps)
+            logits = x[:, -1:] @ params["head.w"].astype(x.dtype)
         cache = {"conv": conv_states.astype(jnp.bfloat16),
                  "ssd": ssd_states.astype(jnp.float32),
                  "pos": jnp.full((), S, jnp.int32)}
@@ -290,30 +298,37 @@ class Mamba2LM(BaseModel):
 
         def body(carry, lp_cache):
             lp, (conv_c, ssd_c) = lp_cache
-            h = rmsnorm(carry, lp["norm.w"], cfg.norm_eps)
-            proj = h @ lp["in_proj.w"].astype(h.dtype)      # (B, d_in_proj)
-            z, xs, b, c, dt = self._split(proj)
-            xbc = jnp.concatenate([xs, b, c], axis=-1)      # (B, conv_dim)
-            hist = jnp.concatenate([conv_c, xbc[:, None]], axis=1)  # (B,K,cd)
-            w = lp["conv.w"].astype(hist.dtype)             # (K, cd)
-            conv = jnp.einsum("bkc,kc->bc", hist, w)
-            conv = jax.nn.silu(conv + lp["conv.b"].astype(conv.dtype))
-            xs_c, b_c, c_c = (conv[:, :DI], conv[:, DI:DI + N],
-                              conv[:, DI + N:])
-            dt = jax.nn.softplus(dt.astype(jnp.float32) +
-                                 lp["dt_bias"].astype(jnp.float32))
-            y, new_ssd = ssd_decode_step(
-                ssd_c, xs_c.reshape(B, H, P), dt, lp["a_log"], b_c, c_c,
-                lp["d_skip"])
-            y = y.reshape(B, DI) * jax.nn.silu(
-                z.astype(jnp.float32)).astype(y.dtype)
-            y = rmsnorm(y, lp["gate_norm.w"], cfg.norm_eps)
-            out = carry + y @ lp["out_proj.w"].astype(y.dtype)
+            with jax.named_scope("norm"):
+                h = rmsnorm(carry, lp["norm.w"], cfg.norm_eps)
+            with jax.named_scope("in_proj"):
+                proj = h @ lp["in_proj.w"].astype(h.dtype)  # (B, d_in_proj)
+                z, xs, b, c, dt = self._split(proj)
+            with jax.named_scope("conv"):
+                xbc = jnp.concatenate([xs, b, c], axis=-1)  # (B, conv_dim)
+                hist = jnp.concatenate([conv_c, xbc[:, None]],
+                                       axis=1)              # (B, K, cd)
+                w = lp["conv.w"].astype(hist.dtype)         # (K, cd)
+                conv = jnp.einsum("bkc,kc->bc", hist, w)
+                conv = jax.nn.silu(conv + lp["conv.b"].astype(conv.dtype))
+                xs_c, b_c, c_c = (conv[:, :DI], conv[:, DI:DI + N],
+                                  conv[:, DI + N:])
+            with jax.named_scope("ssd"):
+                dt = jax.nn.softplus(dt.astype(jnp.float32) +
+                                     lp["dt_bias"].astype(jnp.float32))
+                y, new_ssd = ssd_decode_step(
+                    ssd_c, xs_c.reshape(B, H, P), dt, lp["a_log"], b_c, c_c,
+                    lp["d_skip"])
+            with jax.named_scope("out_proj"):  # gate, gate norm, projection
+                y = y.reshape(B, DI) * jax.nn.silu(
+                    z.astype(jnp.float32)).astype(y.dtype)
+                y = rmsnorm(y, lp["gate_norm.w"], cfg.norm_eps)
+                out = carry + y @ lp["out_proj.w"].astype(y.dtype)
             return out, (hist[:, 1:].astype(jnp.bfloat16), new_ssd)
 
         x, (new_conv, new_ssd) = jax.lax.scan(
             body, x, (stacked, (cache["conv"], cache["ssd"])))
-        x = rmsnorm(x, params["final_norm.w"], cfg.norm_eps)
-        logits = (x @ params["head.w"].astype(x.dtype))[:, None, :]
+        with jax.named_scope("head"):
+            x = rmsnorm(x, params["final_norm.w"], cfg.norm_eps)
+            logits = (x @ params["head.w"].astype(x.dtype))[:, None, :]
         return logits, {"conv": new_conv, "ssd": new_ssd,
                         "pos": cache["pos"] + 1}
