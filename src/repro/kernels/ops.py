@@ -43,7 +43,7 @@ def _use_pallas(force: str | None) -> tuple[bool, bool]:
     return jax.default_backend() == "tpu", False
 
 
-def matmul(x, y, *, bm=128, bn=128, bk=128, out_dtype=None, force=None):
+def matmul(x, y, *, bm=None, bn=None, bk=None, out_dtype=None, force=None):
     pallas, interp = _use_pallas(force)
     if pallas:
         return _matmul.matmul(x, y, bm=bm, bn=bn, bk=bk, out_dtype=out_dtype,

@@ -98,10 +98,10 @@ class ZooTenant:
             return prefill_slab
 
         def make_decode(copy_op) -> Callable[[], None]:
-            # the burst's GEMV is a single row, below the Pallas matmul's
-            # 128-row tile, so it is pinned to XLA (force="ref") on every
-            # backend; a variant only swaps the copy kernel (the
-            # class-defining op)
+            # the burst's GEMV is a single row, which no 128-multiple tile
+            # of the Pallas matmul divides, so it is pinned to XLA
+            # (force="ref") on every backend; a variant only swaps the copy
+            # kernel (the class-defining op)
             def decode_burst() -> None:
                 for _ in range(self.decode_steps):
                     moved = copy_op(cache_slab)

@@ -7,7 +7,7 @@ import pytest
 pytest.importorskip("hypothesis")  # dev-only dep (requirements-dev.txt): skip, not error
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import ops, ref
+from repro.kernels import matmul, ops, ref
 
 RNG = np.random.default_rng(42)
 
@@ -39,6 +39,43 @@ def test_matmul_rejects_untiled():
     with pytest.raises(ValueError):
         ops.matmul(_arr((100, 128), jnp.float32), _arr((128, 128), jnp.float32),
                    force="interpret")
+
+
+@pytest.mark.parametrize("m,k,n,dtype,want", [
+    # paper-dag coarse; 1024^3 would need 18 MiB, so bk halves, not bm*bn
+    (4096, 4096, 4096, jnp.bfloat16, (1024, 1024, 512)),
+    (256, 256, 256, jnp.float32, (256, 256, 256)),        # paper-dag fine
+    (256, 2048, 8192, jnp.bfloat16, (256, 1024, 1024)),   # zoo prefill slab
+    # the budget keeps bm*bn at half the ladder's top and bk at a quarter
+    (4096, 4096, 4096, jnp.float32, (512, 1024, 256)),
+    (384, 128, 640, jnp.float32, (128, 128, 128)),
+])
+def test_choose_tiles(m, k, n, dtype, want):
+    bm, bn, bk = matmul.choose_tiles(m, k, n, dtype)
+    assert (bm, bn, bk) == want
+    for d, t in ((m, bm), (n, bn), (k, bk)):
+        assert t % 128 == 0 and d % t == 0
+    assert matmul.vmem_bytes(bm, bn, bk, dtype) <= matmul.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("shape", [(100, 128, 128), (128, 100, 128),
+                                   (128, 128, 100)])
+def test_choose_tiles_rejects_untiled(shape):
+    with pytest.raises(ValueError):
+        matmul.choose_tiles(*shape, jnp.float32)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 5e-5),
+                                       (jnp.bfloat16, 2e-2)])
+def test_matmul_chosen_tiles_match_ref(dtype, tol):
+    m, k, n = 512, 1024, 512
+    assert matmul.choose_tiles(m, k, n, dtype) != (128, 128, 128)
+    x, y = _arr((m, k), dtype), _arr((k, n), dtype)
+    got = ops.matmul(x, y, force="interpret")
+    want = ref.matmul(x, y)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol * 10)
 
 
 # ------------------------------------------------------------ copy/triad --

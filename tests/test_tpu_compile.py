@@ -59,6 +59,8 @@ def _compile(fn, shapes, sharding, **static):
     (256, 256, 256, F32),           # the zoo's prefill projection
     (2048, 2048, 2048, BF16),
     (256, 2048, 8192, BF16),        # llama3.2-1b MLP up-projection slab
+    (4096, 4096, 4096, BF16),       # paper-dag coarse payload
+    (1024, 1024, 1024, F32),        # HIGHEST's bf16 split in the budget
 ])
 def test_matmul_compiles(one_chip, m, k, n, dtype):
     _compile(matmul.matmul, [((m, k), dtype), ((k, n), dtype)], one_chip)
