@@ -1,4 +1,4 @@
-"""repro.configs — the 10 assigned architectures, the 4 input shapes, and
+"""repro.configs — the 11 architectures, the 4 input shapes, and
 the (arch x shape) cell matrix with structural-skip logic.
 
 Every architecture is selectable as ``--arch <id>`` in the launchers; each
@@ -28,6 +28,7 @@ ARCH_IDS = (
     "chatglm3-6b",
     "llama3-8b",
     "hymba-1.5b",
+    "falcon-h1-34b",
 )
 
 _MODULES = {
@@ -41,6 +42,7 @@ _MODULES = {
     "chatglm3-6b": "chatglm3_6b",
     "llama3-8b": "llama3_8b",
     "hymba-1.5b": "hymba_1_5b",
+    "falcon-h1-34b": "falcon_h1_34b",
 }
 
 

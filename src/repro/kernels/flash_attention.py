@@ -126,6 +126,7 @@ def flash_attention(
         causal=causal, window=window, sm_scale=sm_scale)
     return pl.pallas_call(
         kernel,
+        name="flash_attention",      # the kernel's op name in a device trace
         grid=(b, hq, s // bq, kv_steps),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda bb, h, i, j: (bb, h, i, 0)),

@@ -10,6 +10,7 @@ from .model_api import BaseModel, ModelConfig, ParamDef
 from .transformer import DecoderLM
 from .mamba2 import Mamba2LM
 from .hybrid import HymbaLM
+from .falcon_h1 import FalconH1LM
 
 
 def get_model(cfg: ModelConfig) -> BaseModel:
@@ -19,6 +20,8 @@ def get_model(cfg: ModelConfig) -> BaseModel:
         return Mamba2LM(cfg)
     if cfg.family == "hybrid":
         return HymbaLM(cfg)
+    if cfg.family == "falcon_h1":
+        return FalconH1LM(cfg)
     raise ValueError(f"unknown family {cfg.family!r}")
 
 
@@ -67,6 +70,6 @@ def make_encode_step(model: BaseModel):
 
 __all__ = [
     "BaseModel", "ModelConfig", "ParamDef", "DecoderLM", "Mamba2LM",
-    "HymbaLM", "get_model", "make_train_step", "make_prefill_step",
+    "HymbaLM", "FalconH1LM", "get_model", "make_train_step", "make_prefill_step",
     "make_decode_step", "make_encode_step",
 ]

@@ -98,7 +98,7 @@ class HymbaLM(BaseModel):
         dt = jax.nn.softplus(dt.astype(jnp.float32) +
                              lp["ssm.dt_bias"].astype(jnp.float32))
         y, final = ssd_chunked(xs.reshape(B, S, H, P), dt, lp["ssm.a_log"],
-                               b, c, lp["ssm.d_skip"],
+                               b[:, :, None], c[:, :, None], lp["ssm.d_skip"],
                                chunk=min(cfg.ssm_chunk, S),
                                shard_acts=cfg.ssd_shard_acts)
         y = y.reshape(B, S, DI) * jax.nn.silu(
@@ -303,7 +303,7 @@ class HymbaLM(BaseModel):
                                   lp["ssm.dt_bias"].astype(jnp.float32))
             y, ssd_next = ssd_decode_step(
                 cache["ssd"][i], xs_c.reshape(B, H, P), dtp, lp["ssm.a_log"],
-                b_c, c_c, lp["ssm.d_skip"])
+                b_c[:, None], c_c[:, None], lp["ssm.d_skip"])
             y = y.reshape(B, DI) * jax.nn.silu(
                 z.astype(jnp.float32)).astype(y.dtype)
             ssm_out = y[:, None, :]

@@ -15,6 +15,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..parallel.sharding import constrain, logical_sharding
 from .layers import rmsnorm
@@ -27,12 +28,43 @@ from .model_api import BaseModel, ModelConfig, ParamDef
 # ---------------------------------------------------------------------------
 def ssd_chunked(x, dt, a_log, b, c, d_skip, chunk: int,
                 shard_acts: bool = False):
-    """SSD in the chunked dual form.
+    """SSD in the chunked dual form, with G groups of B/C.
 
     x:  (B, L, H, P)   inputs per head
     dt: (B, L, H)      softplus'd step sizes
     a_log: (H,)        -A = exp(a_log) > 0
-    b, c: (B, L, N)    input/output projections (G=1 group, shared over H)
+    b, c: (B, L, G, N) input/output projections; H % G == 0 and head h
+                       reads group h // (H // G)
+    d_skip: (H,)       skip connection
+    Returns (y: (B, L, H, P), final_state: (B, H, N, P)).  One group runs
+    :func:`_ssd_chunked_group` as it is; G groups run it once per group.
+    """
+    B, L, H, P = x.shape
+    G, N = b.shape[-2:]
+    if H % G:
+        raise ValueError(f"{H} SSD heads do not split into {G} groups")
+    if G == 1:
+        return _ssd_chunked_group(x, dt, a_log, b.reshape(B, L, N),
+                                  c.reshape(B, L, N), d_skip, chunk,
+                                  shard_acts)
+    R = H // G
+    y, state = jax.vmap(
+        functools.partial(_ssd_chunked_group, chunk=chunk,
+                          shard_acts=shard_acts),
+        in_axes=(2, 2, 0, 2, 2, 0), out_axes=(2, 1))(
+            x.reshape(B, L, G, R, P), dt.reshape(B, L, G, R),
+            a_log.reshape(G, R), b, c, d_skip.reshape(G, R))
+    return y.reshape(B, L, H, P), state.reshape(B, H, N, P)
+
+
+def _ssd_chunked_group(x, dt, a_log, b, c, d_skip, chunk: int,
+                       shard_acts: bool = False):
+    """SSD in the chunked dual form for heads that share one B/C group.
+
+    x:  (B, L, H, P)   inputs per head
+    dt: (B, L, H)      softplus'd step sizes
+    a_log: (H,)        -A = exp(a_log) > 0
+    b, c: (B, L, N)    input/output projections, shared over H
     d_skip: (H,)       skip connection
     ``shard_acts`` adds batch-sharding constraints on the big intra-chunk
     temporaries (the decay tensor is O(B*L*chunk*H) — without constraints
@@ -117,7 +149,27 @@ def ssd_chunked(x, dt, a_log, b, c, d_skip, chunk: int,
 
 def ssd_decode_step(state, x_t, dt_t, a_log, b_t, c_t, d_skip):
     """One recurrent step.  state: (B,H,N,P); x_t: (B,H,P); dt_t: (B,H);
-    b_t/c_t: (B,N).  Returns (y_t, new_state)."""
+    b_t/c_t: (B,G,N), head h reading group h // (H // G).  Returns (y_t,
+    new_state)."""
+    B, H, N, P = state.shape
+    G = b_t.shape[1]
+    if H % G:
+        raise ValueError(f"{H} SSD heads do not split into {G} groups")
+    if G == 1:
+        return _ssd_step_group(state, x_t, dt_t, a_log, b_t.reshape(B, N),
+                               c_t.reshape(B, N), d_skip)
+    R = H // G
+    y, new_state = jax.vmap(
+        _ssd_step_group, in_axes=(1, 1, 1, 0, 1, 1, 0), out_axes=(1, 1))(
+            state.reshape(B, G, R, N, P), x_t.reshape(B, G, R, P),
+            dt_t.reshape(B, G, R), a_log.reshape(G, R), b_t, c_t,
+            d_skip.reshape(G, R))
+    return y.reshape(B, H, P), new_state.reshape(B, H, N, P)
+
+
+def _ssd_step_group(state, x_t, dt_t, a_log, b_t, c_t, d_skip):
+    """One recurrent step of heads that share one B/C group.  b_t/c_t:
+    (B,N)."""
     A = -jnp.exp(a_log.astype(jnp.float32))
     dA = jnp.exp(dt_t.astype(jnp.float32) * A[None, :])       # (B,H)
     upd = jnp.einsum("bn,bh,bhp->bhnp", b_t.astype(jnp.float32),
@@ -129,86 +181,177 @@ def ssd_decode_step(state, x_t, dt_t, a_log, b_t, c_t, d_skip):
 
 
 # ---------------------------------------------------------------------------
+# the Mamba-2 mixer, shared by Mamba2LM and FalconH1LM
+# ---------------------------------------------------------------------------
+def mixer_widths(cfg: ModelConfig) -> tuple[int, int]:
+    """-> (conv_dim, d_in_proj): in_proj emits [z | x | B | C | dt], the
+    convolution runs over [x | B | C]."""
+    gn = cfg.ssm_groups * cfg.ssm_state
+    conv_dim = cfg.d_inner + 2 * gn
+    return conv_dim, cfg.d_inner + conv_dim + cfg.ssm_heads
+
+
+def mixer_param_defs(cfg: ModelConfig, dtype=jnp.float32) -> dict:
+    """The mixer's stacked parameters, named under ``layers.``; ``dtype``
+    is that of the projections and the convolution."""
+    L, M = cfg.n_layers, cfg.d_model
+    DI, H = cfg.d_inner, cfg.ssm_heads
+    conv_dim, d_in_proj = mixer_widths(cfg)
+    return {
+        "layers.in_proj.w": ParamDef((L, M, d_in_proj),
+                                     ("layers", "embed", "ff"), dtype=dtype),
+        "layers.conv.w": ParamDef((L, cfg.ssm_conv, conv_dim),
+                                  ("layers", None, "ff"), dtype=dtype),
+        "layers.conv.b": ParamDef((L, conv_dim), ("layers", "ff"),
+                                  init="zeros", dtype=dtype),
+        "layers.a_log": ParamDef((L, H), ("layers", None), init="ssm_a"),
+        "layers.d_skip": ParamDef((L, H), ("layers", None), init="ones"),
+        "layers.dt_bias": ParamDef((L, H), ("layers", None),
+                                   init="ssm_dt"),
+        "layers.gate_norm.w": ParamDef((L, DI), ("layers", "ff"),
+                                       init="ones"),
+        "layers.out_proj.w": ParamDef((L, DI, M), ("layers", "ff", "embed"),
+                                      dtype=dtype),
+    }
+
+
+def _mup_vector(cfg: ModelConfig) -> np.ndarray:
+    """Per-channel multipliers of in_proj's output, ``ssm_multipliers`` on
+    [z | x | B | C | dt] (Falcon-H1's ``compute_mup_vector``)."""
+    gn = cfg.ssm_groups * cfg.ssm_state
+    sizes = (cfg.d_inner, cfg.d_inner, gn, gn, cfg.ssm_heads)
+    return np.concatenate([np.full(n, m, np.float32)
+                           for n, m in zip(sizes, cfg.ssm_multipliers)])
+
+
+def _in_proj(cfg: ModelConfig, p, h):
+    """in_proj with Falcon-H1's input and per-channel multipliers; at unit
+    multipliers (Mamba-2) no multiply is traced."""
+    if cfg.ssm_in_multiplier != 1.0:
+        h = h * cfg.ssm_in_multiplier
+    proj = h @ p["in_proj.w"].astype(h.dtype)
+    if any(m != 1.0 for m in cfg.ssm_multipliers):
+        proj = (proj.astype(jnp.float32) * _mup_vector(cfg)
+                ).astype(proj.dtype)
+    return proj
+
+
+def _split(cfg: ModelConfig, x):
+    """in_proj output -> (z, x, B, C, dt), B and C ``G * N`` wide."""
+    DI, gn = cfg.d_inner, cfg.ssm_groups * cfg.ssm_state
+    z = x[..., :DI]
+    xs = x[..., DI:2 * DI]
+    b = x[..., 2 * DI:2 * DI + gn]
+    c = x[..., 2 * DI + gn:2 * DI + 2 * gn]
+    dt = x[..., 2 * DI + 2 * gn:]
+    return z, xs, b, c, dt
+
+
+def gated_rmsnorm(y, z, w, eps: float, groups: int = 1):
+    """RMSNorm of ``y * silu(z)`` over each of ``groups`` equal slices of
+    the last axis (gate before norm: ``norm_before_gate`` false)."""
+    y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
+    if groups == 1:
+        return rmsnorm(y, w, eps)
+    shape = y.shape
+    y = y.reshape(*shape[:-1], groups, shape[-1] // groups)
+    return rmsnorm(y, w.reshape(groups, -1), eps).reshape(shape)
+
+
+def mixer_full(cfg: ModelConfig, p, h, want_state: bool = False):
+    """The mixer over a whole sequence.  h: (B, S, M), already normalized.
+    Returns (out: (B, S, M) before the residual add,
+    (conv_state, ssd_state) | None)."""
+    B, S, _ = h.shape
+    DI, N, H, P = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
+    G = cfg.ssm_groups
+    with jax.named_scope("in_proj"):
+        proj = _in_proj(cfg, p, h)
+        z, xs, b, c, dt = _split(cfg, proj)
+    with jax.named_scope("conv"):
+        # depthwise causal conv over (xs|b|c)
+        xbc = jnp.concatenate([xs, b, c], axis=-1)   # (B,S,conv_dim)
+        w = p["conv.w"].astype(xbc.dtype)            # (K, conv_dim)
+        K = w.shape[0]
+        pad = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
+        conv = sum(pad[:, i:i + S] * w[i][None, None] for i in range(K))
+        conv = jax.nn.silu(conv + p["conv.b"].astype(conv.dtype))
+        xs, b, c = (conv[..., :DI], conv[..., DI:DI + G * N],
+                    conv[..., DI + G * N:])
+    with jax.named_scope("ssd"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32) +
+                             p["dt_bias"].astype(jnp.float32))
+        y, final_state = ssd_chunked(
+            xs.reshape(B, S, H, P), dt, p["a_log"], b.reshape(B, S, G, N),
+            c.reshape(B, S, G, N), p["d_skip"], chunk=min(cfg.ssm_chunk, S),
+            shard_acts=cfg.ssd_shard_acts)
+    with jax.named_scope("out_proj"):      # gate, gate norm, projection
+        y = gated_rmsnorm(y.reshape(B, S, DI), z, p["gate_norm.w"],
+                          cfg.norm_eps, G)
+        y = constrain(y, "batch", "seq", "act_ff")
+        out = y @ p["out_proj.w"].astype(y.dtype)
+    if not want_state:
+        return out, None
+    conv_state = xbc[:, -(cfg.ssm_conv - 1):]
+    return out, (conv_state.astype(jnp.bfloat16), final_state)
+
+
+def mixer_step(cfg: ModelConfig, p, h, conv_c, ssd_c):
+    """The mixer for one token.  h: (B, M), already normalized; conv_c:
+    (B, K - 1, conv_dim); ssd_c: (B, H, N, P).  Returns (out: (B, M) before
+    the residual add, (new conv_c, new ssd_c))."""
+    B = h.shape[0]
+    DI, N, H, P = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
+    G = cfg.ssm_groups
+    with jax.named_scope("in_proj"):
+        proj = _in_proj(cfg, p, h)                      # (B, d_in_proj)
+        z, xs, b, c, dt = _split(cfg, proj)
+    with jax.named_scope("conv"):
+        xbc = jnp.concatenate([xs, b, c], axis=-1)      # (B, conv_dim)
+        hist = jnp.concatenate([conv_c, xbc[:, None]], axis=1)  # (B, K, cd)
+        w = p["conv.w"].astype(hist.dtype)              # (K, cd)
+        conv = jnp.einsum("bkc,kc->bc", hist, w)
+        conv = jax.nn.silu(conv + p["conv.b"].astype(conv.dtype))
+        xs_c, b_c, c_c = (conv[:, :DI], conv[:, DI:DI + G * N],
+                          conv[:, DI + G * N:])
+    with jax.named_scope("ssd"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32) +
+                             p["dt_bias"].astype(jnp.float32))
+        y, new_ssd = ssd_decode_step(
+            ssd_c, xs_c.reshape(B, H, P), dt, p["a_log"],
+            b_c.reshape(B, G, N), c_c.reshape(B, G, N), p["d_skip"])
+    with jax.named_scope("out_proj"):      # gate, gate norm, projection
+        y = gated_rmsnorm(y.reshape(B, DI), z, p["gate_norm.w"],
+                          cfg.norm_eps, G)
+        out = y @ p["out_proj.w"].astype(y.dtype)
+    return out, (hist[:, 1:].astype(jnp.bfloat16), new_ssd)
+
+
+# ---------------------------------------------------------------------------
 # model
 # ---------------------------------------------------------------------------
 class Mamba2LM(BaseModel):
     def param_defs(self) -> dict:
         cfg = self.cfg
         L, M, V = cfg.n_layers, cfg.d_model, cfg.padded_vocab
-        DI, N, H = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
-        conv_dim = DI + 2 * N
-        d_in_proj = 2 * DI + 2 * N + H
         defs = {
             "embed.w": ParamDef((V, M), ("vocab", "embed")),
             "final_norm.w": ParamDef((M,), (None,), init="ones"),
             "head.w": ParamDef((M, V), ("embed", "vocab")),
             "layers.norm.w": ParamDef((L, M), ("layers", None), init="ones"),
-            "layers.in_proj.w": ParamDef((L, M, d_in_proj),
-                                         ("layers", "embed", "ff")),
-            "layers.conv.w": ParamDef((L, cfg.ssm_conv, conv_dim),
-                                      ("layers", None, "ff")),
-            "layers.conv.b": ParamDef((L, conv_dim), ("layers", "ff"),
-                                      init="zeros"),
-            "layers.a_log": ParamDef((L, H), ("layers", None), init="ssm_a"),
-            "layers.d_skip": ParamDef((L, H), ("layers", None), init="ones"),
-            "layers.dt_bias": ParamDef((L, H), ("layers", None),
-                                       init="ssm_dt"),
-            "layers.gate_norm.w": ParamDef((L, DI), ("layers", "ff"),
-                                           init="ones"),
-            "layers.out_proj.w": ParamDef((L, DI, M),
-                                          ("layers", "ff", "embed")),
         }
+        defs.update(mixer_param_defs(cfg))
         return defs
 
     # --------------------------------------------------------------- layer --
-    def _split(self, x):
-        cfg = self.cfg
-        DI, N, H = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
-        z = x[..., :DI]
-        xs = x[..., DI:2 * DI]
-        b = x[..., 2 * DI:2 * DI + N]
-        c = x[..., 2 * DI + N:2 * DI + 2 * N]
-        dt = x[..., 2 * DI + 2 * N:]
-        return z, xs, b, c, dt
-
     def _layer_full(self, p, x, want_state: bool = False):
         """Full-sequence SSD layer.  x: (B, L_seq, M).  Returns
         (out, (conv_state, ssd_state)|None)."""
         cfg = self.cfg
-        B, S, M = x.shape
-        DI, N, H, P = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
         with jax.named_scope("norm"):
             h = rmsnorm(x, p["norm.w"], cfg.norm_eps)
-        with jax.named_scope("in_proj"):
-            proj = h @ p["in_proj.w"].astype(h.dtype)
-            z, xs, b, c, dt = self._split(proj)
-        with jax.named_scope("conv"):
-            # depthwise causal conv over (xs|b|c)
-            xbc = jnp.concatenate([xs, b, c], axis=-1)   # (B,S,conv_dim)
-            w = p["conv.w"].astype(xbc.dtype)            # (K, conv_dim)
-            K = w.shape[0]
-            pad = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
-            conv = sum(pad[:, i:i + S] * w[i][None, None] for i in range(K))
-            conv = jax.nn.silu(conv + p["conv.b"].astype(conv.dtype))
-            xs, b, c = (conv[..., :DI], conv[..., DI:DI + N],
-                        conv[..., DI + N:])
-        with jax.named_scope("ssd"):
-            dt = jax.nn.softplus(dt.astype(jnp.float32) +
-                                 p["dt_bias"].astype(jnp.float32))
-            y, final_state = ssd_chunked(
-                xs.reshape(B, S, H, P), dt, p["a_log"], b, c,
-                p["d_skip"], chunk=min(cfg.ssm_chunk, S),
-                shard_acts=cfg.ssd_shard_acts)
-        with jax.named_scope("out_proj"):      # gate, gate norm, projection
-            y = y.reshape(B, S, DI) * jax.nn.silu(z.astype(jnp.float32)
-                                                  ).astype(y.dtype)
-            y = rmsnorm(y, p["gate_norm.w"], cfg.norm_eps)
-            y = constrain(y, "batch", "seq", "act_ff")
-            out = x + (y @ p["out_proj.w"].astype(y.dtype))
-        if not want_state:
-            return out, None
-        conv_state = xbc[:, -(cfg.ssm_conv - 1):]
-        return out, (conv_state.astype(jnp.bfloat16), final_state)
+        y, state = mixer_full(cfg, p, h, want_state)
+        return x + y, state
 
     # ------------------------------------------------------------- forward --
     def forward(self, params, batch):
@@ -242,9 +385,8 @@ class Mamba2LM(BaseModel):
     # --------------------------------------------------------------- serve --
     def init_cache(self, batch_size: int, max_len: int, abstract=False):
         cfg = self.cfg
-        DI, N, H, P = (cfg.d_inner, cfg.ssm_state, cfg.ssm_heads,
-                       cfg.ssm_head_dim)
-        conv_dim = DI + 2 * N
+        N, H, P = cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
+        conv_dim, _ = mixer_widths(cfg)
         shapes = {
             "conv": ((cfg.n_layers, batch_size, cfg.ssm_conv - 1, conv_dim),
                      ("layers", "batch", None, "ff"), jnp.bfloat16),
@@ -290,9 +432,6 @@ class Mamba2LM(BaseModel):
         cfg = self.cfg
         stacked = {k[len("layers."):]: v for k, v in params.items()
                    if k.startswith("layers.")}
-        B = tokens.shape[0]
-        DI, N, H, P = (cfg.d_inner, cfg.ssm_state, cfg.ssm_heads,
-                       cfg.ssm_head_dim)
         x = jnp.take(params["embed.w"], tokens[:, 0], axis=0
                      ).astype(jnp.bfloat16)          # (B, M)
 
@@ -300,30 +439,8 @@ class Mamba2LM(BaseModel):
             lp, (conv_c, ssd_c) = lp_cache
             with jax.named_scope("norm"):
                 h = rmsnorm(carry, lp["norm.w"], cfg.norm_eps)
-            with jax.named_scope("in_proj"):
-                proj = h @ lp["in_proj.w"].astype(h.dtype)  # (B, d_in_proj)
-                z, xs, b, c, dt = self._split(proj)
-            with jax.named_scope("conv"):
-                xbc = jnp.concatenate([xs, b, c], axis=-1)  # (B, conv_dim)
-                hist = jnp.concatenate([conv_c, xbc[:, None]],
-                                       axis=1)              # (B, K, cd)
-                w = lp["conv.w"].astype(hist.dtype)         # (K, cd)
-                conv = jnp.einsum("bkc,kc->bc", hist, w)
-                conv = jax.nn.silu(conv + lp["conv.b"].astype(conv.dtype))
-                xs_c, b_c, c_c = (conv[:, :DI], conv[:, DI:DI + N],
-                                  conv[:, DI + N:])
-            with jax.named_scope("ssd"):
-                dt = jax.nn.softplus(dt.astype(jnp.float32) +
-                                     lp["dt_bias"].astype(jnp.float32))
-                y, new_ssd = ssd_decode_step(
-                    ssd_c, xs_c.reshape(B, H, P), dt, lp["a_log"], b_c, c_c,
-                    lp["d_skip"])
-            with jax.named_scope("out_proj"):  # gate, gate norm, projection
-                y = y.reshape(B, DI) * jax.nn.silu(
-                    z.astype(jnp.float32)).astype(y.dtype)
-                y = rmsnorm(y, lp["gate_norm.w"], cfg.norm_eps)
-                out = carry + y @ lp["out_proj.w"].astype(y.dtype)
-            return out, (hist[:, 1:].astype(jnp.bfloat16), new_ssd)
+            y, state = mixer_step(cfg, lp, h, conv_c, ssd_c)
+            return carry + y, state
 
         x, (new_conv, new_ssd) = jax.lax.scan(
             body, x, (stacked, (cache["conv"], cache["ssd"])))

@@ -35,7 +35,8 @@ from ..parallel.sharding import (current_ctx, logical_sharding,
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                  # decoder | ssm | hybrid | encoder
+    family: str                  # decoder | ssm | hybrid | falcon_h1 |
+                                 # encoder
     n_layers: int
     d_model: int
     n_heads: int
@@ -56,10 +57,22 @@ class ModelConfig:
     causal: bool = True                  # encoders set False
     # --- SSM ---
     ssm_state: int = 0
-    ssm_expand: int = 2
+    ssm_expand: float = 2            # d_inner = round(ssm_expand * d_model)
     ssm_head_dim: int = 64
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    ssm_groups: int = 1                  # B/C groups: head h reads group
+                                         # h // (ssm_heads // ssm_groups)
+    # --- muP multipliers (Falcon-H1's published ones; 1.0 = none) ---
+    embedding_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple = (1.0,) * 5  # on in_proj's [z, x, B, C, dt]
+    mlp_multipliers: tuple = (1.0, 1.0)  # gate, down
+    lm_head_multiplier: float = 1.0
     # --- misc ---
     norm_eps: float = 1e-5
     vocab_pad_multiple: int = 256
@@ -106,7 +119,7 @@ class ModelConfig:
 
     @property
     def d_inner(self) -> int:
-        return self.ssm_expand * self.d_model
+        return round(self.ssm_expand * self.d_model)
 
     @property
     def ssm_heads(self) -> int:

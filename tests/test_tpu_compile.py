@@ -89,11 +89,14 @@ def test_sort_rows_compiles(one_chip, rows, n):
     _compile(sort_bitonic.sort_rows, [((rows, n), F32)], one_chip)
 
 
-@pytest.mark.parametrize("hq,hkv,s,dtype", [
-    (4, 4, 256, F32),               # the zoo's prefill slab
-    (32, 8, 2048, BF16),            # llama3.2-1b GQA at a 2k prompt
-])
-def test_flash_attention_compiles(one_chip, hq, hkv, s, dtype):
+@pytest.mark.parametrize("hq,hkv,s,d,dtype", [
+    (4, 4, 256, 64, F32),           # the zoo's prefill slab
+    (32, 8, 2048, 64, BF16),        # llama3.2-1b GQA at a 2k prompt
+    (20, 4, 4096, 128, BF16),       # falcon-h1-34b, the shortest prompt
+    (20, 4, 32768, 128, BF16),      # falcon-h1-34b, the longest prompt
+], ids=["4-4-256-float32", "32-8-2048-bfloat16",   # as before head_dim
+        "20-4-4096-128-bfloat16", "20-4-32768-128-bfloat16"])
+def test_flash_attention_compiles(one_chip, hq, hkv, s, d, dtype):
     _compile(flash_attention.flash_attention,
-             [((1, hq, s, 64), dtype), ((1, hkv, s, 64), dtype),
-              ((1, hkv, s, 64), dtype)], one_chip)
+             [((1, hq, s, d), dtype), ((1, hkv, s, d), dtype),
+              ((1, hkv, s, d), dtype)], one_chip)
