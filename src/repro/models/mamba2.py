@@ -16,6 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..parallel.sharding import constrain, logical_sharding
 from .layers import rmsnorm
@@ -224,12 +225,31 @@ def _mup_vector(cfg: ModelConfig) -> np.ndarray:
                            for n, m in zip(sizes, cfg.ssm_multipliers)])
 
 
-def _in_proj(cfg: ModelConfig, p, h):
+def step_proj(h, w):
+    """``h @ w`` in ``h``'s dtype for the one-token step.  A weight stored
+    wider than ``h`` (float32 beside bfloat16 activations) is read as stored
+    and rounded to ``h``'s precision inside the product, which sums in
+    float32: the operands are the values ``w.astype(h.dtype)`` holds, but no
+    separate pass writes a converted copy of the weight (XLA hoists such a
+    cast of a whole layer stack out of the layer scan, and pays it every
+    step).  A weight stored in ``h``'s dtype takes the plain product."""
+    if jnp.dtype(w.dtype).itemsize <= jnp.dtype(h.dtype).itemsize:
+        return h @ w.astype(h.dtype)
+    fi = jnp.finfo(h.dtype)
+    w = lax.reduce_precision(w, exponent_bits=fi.nexp, mantissa_bits=fi.nmant)
+    out = lax.dot_general(h, w, (((h.ndim - 1,), (0,)), ((), ())),
+                          preferred_element_type=jnp.float32)
+    return out.astype(h.dtype)
+
+
+def _in_proj(cfg: ModelConfig, p, h, step: bool = False):
     """in_proj with Falcon-H1's input and per-channel multipliers; at unit
-    multipliers (Mamba-2) no multiply is traced."""
+    multipliers (Mamba-2) no multiply is traced.  ``step``: the one-token
+    step's product, :func:`step_proj`."""
     if cfg.ssm_in_multiplier != 1.0:
         h = h * cfg.ssm_in_multiplier
-    proj = h @ p["in_proj.w"].astype(h.dtype)
+    w = p["in_proj.w"]
+    proj = step_proj(h, w) if step else h @ w.astype(h.dtype)
     if any(m != 1.0 for m in cfg.ssm_multipliers):
         proj = (proj.astype(jnp.float32) * _mup_vector(cfg)
                 ).astype(proj.dtype)
@@ -304,7 +324,7 @@ def mixer_step(cfg: ModelConfig, p, h, conv_c, ssd_c):
     DI, N, H, P = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
     G = cfg.ssm_groups
     with jax.named_scope("in_proj"):
-        proj = _in_proj(cfg, p, h)                      # (B, d_in_proj)
+        proj = _in_proj(cfg, p, h, step=True)           # (B, d_in_proj)
         z, xs, b, c, dt = _split(cfg, proj)
     with jax.named_scope("conv"):
         xbc = jnp.concatenate([xs, b, c], axis=-1)      # (B, conv_dim)
@@ -323,7 +343,7 @@ def mixer_step(cfg: ModelConfig, p, h, conv_c, ssd_c):
     with jax.named_scope("out_proj"):      # gate, gate norm, projection
         y = gated_rmsnorm(y.reshape(B, DI), z, p["gate_norm.w"],
                           cfg.norm_eps, G)
-        out = y @ p["out_proj.w"].astype(y.dtype)
+        out = step_proj(y, p["out_proj.w"])
     return out, (hist[:, 1:].astype(jnp.bfloat16), new_ssd)
 
 
@@ -446,6 +466,6 @@ class Mamba2LM(BaseModel):
             body, x, (stacked, (cache["conv"], cache["ssd"])))
         with jax.named_scope("head"):
             x = rmsnorm(x, params["final_norm.w"], cfg.norm_eps)
-            logits = (x @ params["head.w"].astype(x.dtype))[:, None, :]
+            logits = step_proj(x, params["head.w"])[:, None, :]
         return logits, {"conv": new_conv, "ssd": new_ssd,
                         "pos": cache["pos"] + 1}

@@ -150,3 +150,72 @@ def test_loss_decreases_over_steps():
             first = float(metrics["loss"])
     last = float(metrics["loss"])
     assert last < first * 0.7, f"loss {first} -> {last}: not learning"
+
+
+
+def _mamba2_smoke_decode():
+    cfg = get_smoke_config("mamba2-780m")
+    model = get_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(3), (2, 17), 0,
+                              cfg.vocab_size)
+    return model, params, toks, jax.jit(model.decode_step)
+
+
+def _step_rounds_inside():
+    """The step's float32 weights against the same weights rounded to
+    bfloat16 values and stored as float32: bit for bit the same logits and
+    state."""
+    model, params, toks, decode = _mamba2_smoke_decode()
+    rounded = dict(params)
+    for name in ("layers.in_proj.w", "layers.out_proj.w", "head.w"):
+        rounded[name] = params[name].astype(jnp.bfloat16).astype(jnp.float32)
+        assert not np.array_equal(rounded[name], params[name])
+    _, cache = model.prefill(params, {"tokens": toks[:, :-1]})
+    got = jax.tree.leaves(decode(params, toks[:, -1:], cache))
+    want = jax.tree.leaves(decode(rounded, toks[:, -1:], cache))
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+
+
+def _step_matches_prefill_and_forward():
+    """Two decode steps after a prefill follow the forward pass, within
+    test_arch_prefill_decode_consistency's tolerances."""
+    model, params, toks, decode = _mamba2_smoke_decode()
+    S = toks.shape[1]
+    full = np.asarray(model.forward(params, {"tokens": toks}), np.float32)
+    pre, cache = model.prefill(params, {"tokens": toks[:, :-2]})
+    np.testing.assert_allclose(np.asarray(pre[:, 0], np.float32),
+                               full[:, S - 3], rtol=3e-2, atol=3e-2)
+    for i in (S - 2, S - 1):
+        logits, cache = decode(params, toks[:, i:i + 1], cache)
+        np.testing.assert_allclose(np.asarray(logits[:, 0], np.float32),
+                                   full[:, i], rtol=3e-2, atol=3e-2)
+    assert int(cache["pos"]) == S
+
+
+def _step_plain_dot_for_bf16():
+    """Weights stored as bfloat16 (Falcon-H1's) take the plain product;
+    float32 weights are rounded inside it."""
+    from repro.models.mamba2 import step_proj
+    h = jnp.ones((1, 8), jnp.bfloat16)
+
+    def prims(dtype):
+        jaxpr = jax.make_jaxpr(step_proj)(h, jnp.ones((8, 4), dtype))
+        return [e.primitive.name for e in jaxpr.eqns]
+
+    assert prims(jnp.bfloat16) == ["dot_general"]
+    assert "reduce_precision" in prims(jnp.float32)
+
+
+_STEP_CASES = {"rounds_inside": _step_rounds_inside,
+               "matches_prefill_and_forward": _step_matches_prefill_and_forward,
+               "plain_dot_for_bf16": _step_plain_dot_for_bf16}
+
+
+@pytest.mark.parametrize("case", list(_STEP_CASES))
+def test_mamba2_step_projection(case):
+    """The one-token step's projections (``mamba2.step_proj``) round float32
+    weights to bfloat16 inside the product and leave bfloat16 weights on
+    the plain product."""
+    _STEP_CASES[case]()

@@ -3,11 +3,15 @@
 The TPU compiler is installed with jax, and it compiles for a topology that
 is described rather than attached.  This catches what interpret mode
 cannot: layouts Mosaic refuses, tiles not aligned to the hardware, VMEM
-overuse.  Nothing runs, so these tests say nothing about results or times.
+overuse.  Two tests read the compiled text of a model's decode step, to pin
+where its weights are converted.  Nothing runs, so these tests say nothing
+about results or times.
 The topology is described inside a fixture, never at import, so that only
 the worker that runs this file loads the TPU library.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -100,3 +104,64 @@ def test_flash_attention_compiles(one_chip, hq, hkv, s, d, dtype):
     _compile(flash_attention.flash_attention,
              [((1, hq, s, d), dtype), ((1, hkv, s, d), dtype),
               ((1, hkv, s, d), dtype)], one_chip)
+
+
+def _decode_step_hlo(model, one_chip, max_len=64):
+    """Compiled text of ``model.decode_step`` at batch 1 for one chip."""
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = {k: jax.ShapeDtypeStruct(d.shape, d.dtype, sharding=one_chip)
+              for k, d in model.param_defs().items()}
+    cache = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: model.init_cache(1, max_len)))
+    tok = jax.ShapeDtypeStruct((1, 1), jnp.int32, sharding=one_chip)
+    return jax.jit(model.decode_step).lower(params, tok, cache).compile(
+        ).as_text()
+
+
+def _fusions(text):
+    """Fused computations of a compiled HLO text: name -> body."""
+    out, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"%(\S*fus\S*) \(", line)
+        if head and line.endswith("{"):
+            name, out[head.group(1)] = head.group(1), []
+        elif line == "}":
+            name = None
+        elif name:
+            out[name].append(line)
+    return out
+
+
+def test_mamba2_decode_rounds_weights_inside_the_matvec(one_chip):
+    """mamba2-780m's widths, 2 layers: no pass writes a bfloat16 copy of
+    ``in_proj.w``, ``out_proj.w`` or ``head.w``; each is rounded by a
+    ``reduce-precision`` in the fusion that reads it."""
+    from repro.configs.mamba2_780m import config
+    from repro.models import get_model
+    model = get_model(dataclasses.replace(config(), n_layers=2))
+    defs = model.param_defs()
+    weights = {tuple(defs[k].shape[-2:])
+               for k in ("layers.in_proj.w", "layers.out_proj.w", "head.w")}
+    text = _decode_step_hlo(model, one_chip)
+    shape = r"\[([\d,]+)\]"
+    converted = {tuple(int(n) for n in m.split(","))[-2:]
+                 for m in re.findall(r"= bf16" + shape + r"\S* convert\(",
+                                     text)}
+    assert not converted & weights
+    rounded = {tuple(int(n) for n in m.split(","))[-2:]
+               for body in _fusions(text).values() for line in body
+               for m in re.findall(r"= f32" + shape + r"\S* reduce-precision",
+                                   line)}
+    assert weights <= rounded
+
+
+def test_falcon_h1_decode_has_no_rounding(one_chip):
+    """Falcon-H1 stores its weights in bfloat16: its decode step keeps the
+    plain product, with no ``reduce-precision``."""
+    from repro.configs.falcon_h1_34b import config
+    from repro.models import get_model
+    model = get_model(dataclasses.replace(config(), n_layers=1,
+                                          vocab_size=32640))
+    assert "reduce-precision" not in _decode_step_hlo(model, one_chip)
