@@ -57,17 +57,14 @@ def main() -> None:
     for typ, cells in sorted(out.ptt_profiles.items()):
         if not cells:
             continue
-        # keys are (leader, width) for the default implementation and
-        # (leader, width, impl) for measured variants (multi-impl zoo
-        # tenants, see benchmarks/run.py --workload impl): fold them into a
-        # per-(class, impl, width) view of what the scheduler learned
-        by_impl_width: dict = {}
-        for key, t in cells.items():
-            impl = key[2] if len(key) == 3 else "default"
-            by_impl_width.setdefault((impl, key[1]), []).append(t)
+        # keys are (leader, width): fold them into a per-width view of
+        # what the scheduler learned
+        by_width: dict = {}
+        for (_, width), t in cells.items():
+            by_width.setdefault(width, []).append(t)
         print(f"  measured PTT[{typ}]: {len(cells)} cells")
-        for (impl, width), ts in sorted(by_impl_width.items()):
-            print(f"    impl={impl:10s} w={width}: {len(ts):2d} cells, "
+        for width, ts in sorted(by_width.items()):
+            print(f"    w={width}: {len(ts):2d} cells, "
                   f"fastest {min(ts) * 1e3:.2f} ms")
 
 

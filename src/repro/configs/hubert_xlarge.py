@@ -1,10 +1,9 @@
 """hubert-xlarge [audio] — encoder-only transformer backbone (the conv
-feature extractor is a STUB: ``input_specs`` provides precomputed frame
+feature extractor is a STUB: the batch carries precomputed frame
 embeddings).  [arXiv:2106.07447]
 
 48L d_model=1280 16H (kv=16) d_ff=5120 vocab=504 (cluster targets).
-Encoder-only: no decode step -> decode_32k and long_500k are skipped;
-prefill_32k runs as a full bidirectional encode.
+Encoder-only: no decode step; a prefill is a full bidirectional encode.
 """
 from ..models import ModelConfig
 

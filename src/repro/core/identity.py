@@ -6,9 +6,10 @@ branch onto the exact legacy code path, draw the same RNG stream, and produce
 the same schedule byte for byte.  This module is the shared contract for that
 guarantee — a stable fingerprint over a simulator/runtime trace, the
 canonical single-variant configurations, and their pinned signatures captured
-on the pre-variant stack.  ``tests/test_impl_identity.py`` asserts the pins;
-``benchmarks/run.py --workload impl`` and the CI smoke re-assert them so a
-violation fails loudly (identity is deterministic — never a timing flake).
+on the pre-variant stack.  ``tests/test_impl_identity.py`` asserts the pins
+and ``tests/test_shard.py`` re-asserts them through the sharded path at
+``n_shards=1``, so a violation fails loudly (identity is deterministic —
+never a timing flake).
 
 The fingerprint deliberately excludes ``TraceRecord.impl``: the field did not
 exist on the pre-variant stack, and single-variant runs always record

@@ -19,7 +19,7 @@ missing half of the model:
 * :func:`replay_moved_bytes` — recomputes moved bytes from a finished trace
   by replaying the residency automaton, the independent side of the
   conservation invariant (bytes moved == off-resident placements x footprint
-  bytes) the bench and the property tests assert.
+  bytes) that ``tests/test_locality.py`` asserts on both vehicles.
 
 Placement charging itself happens in ``repro.core.policies`` via
 ``LocalityTracker.penalties`` (a per-cluster extra-seconds vector handed to

@@ -360,7 +360,7 @@ class BacklogPreemption(PreemptionController):
     *other* tenant has backlog waiting (a single-tenant or fully-drained
     pool would otherwise self-preempt for pure overhead).  A tenant is
     only ever displaced while it dominates the pool's backlog and never
-    for its own arrivals — on the bursty bench the steady tenant is
+    for its own arrivals — on the bursty stream the steady tenant is
     never dominance-throttled, so it is never the victim.
     """
 

@@ -333,10 +333,10 @@ class SchedulerCore:
     def reset_learning(self, seed: int | None = None) -> None:
         """Forget everything *learned* — PTT profiles (all impls), adaptive
         policy state — zero the per-run counters and restart the RNG stream
-        (from the construction seed unless overridden).  The benchmark
-        harness calls this between A/B legs so profiles learned in one leg
-        cannot leak into the next: a leg run after ``reset_learning`` is
-        byte-identical to one on a freshly-built core."""
+        (from the construction seed unless overridden).  Call it between
+        A/B legs so profiles learned in one leg cannot leak into the next:
+        a leg run after ``reset_learning`` is byte-identical to one on a
+        freshly-built core."""
         self.ptt.reset()
         self.policy.reset()
         self.locality.reset()

@@ -2,10 +2,11 @@
 
 PR 3 made every per-TAO decision O(1)-amortized, but all decisions still
 funnel through one :class:`~repro.core.scheduler.SchedulerCore` under one
-lock — at fleet sizes (10k-100k workers) the *central scheduler* is the
-ceiling, not the workers.  This module partitions scheduling state the way
-the source paper's random-work-stealing baseline stays scalable
-(decentralization), while keeping the PTT-driven placement the paper adds:
+lock — at large enough fleets the *central scheduler* becomes the
+ceiling, not the workers (no measurement in the tree shows at what size).
+This module partitions scheduling state the way the source paper's
+random-work-stealing baseline stays scalable (decentralization), while
+keeping the PTT-driven placement the paper adds:
 
 * :class:`ShardMap` — a deterministic, capacity-weighted ``dag_id -> shard``
   route.  A pure function of the dag_id, so admission *order* can never
@@ -38,7 +39,7 @@ Identity contract (the PR 3/7/9 pattern): with ``n_shards=1`` the single
 shard *is* the full spec — same seed, same policy object, same
 ``LocalityTracker`` instance, identity id-translation — so every pinned
 trace signature reproduces byte-for-byte through the sharded code path
-(CI-gated via ``benchmarks/perf.py --shards``).  ``reset_counters`` /
+(gated by ``tests/test_shard.py``).  ``reset_counters`` /
 ``reset_learning`` clear the exchange/imbalance state alongside the
 per-shard core state, preserving the PR 7 leg-identity guarantee.
 

@@ -61,8 +61,9 @@ run one Simulator instance from two threads.
 Fast/slow-path invariant: ``fast_dispatch`` (bitmask idle/non-empty sets,
 O(1) interference counters, O(k) water-filling) and the PTT's
 ``fast_query`` change *data structures only* — for the same seed the fast
-and slow paths schedule byte-identically, which ``benchmarks/perf.py``
-asserts as full trace equality in CI.
+and slow paths schedule byte-identically, which
+``tests/test_fast_paths.py`` asserts as full trace equality at fleets of 16,
+64 and 256 workers.
 """
 from __future__ import annotations
 
@@ -73,8 +74,6 @@ import math
 import random
 from collections import deque
 from typing import Callable
-
-import numpy as np
 
 from .dag import DEFAULT_IMPL, TAO, TaoDag
 from .places import BIG, LITTLE, ClusterSpec
@@ -198,7 +197,7 @@ class _BitSet:
     member with ``k = rng.randrange(len(self))`` consumes the same RNG state
     and returns the very same worker as the seed scan — so the fast dispatch
     path schedules *byte-identically* to ``fast_dispatch=False``, which is
-    what lets the perf suite assert trace equality instead of similarity.
+    what lets the tests assert trace equality instead of similarity.
 
     Cost: the mask is a list of 64-bit words, so add / discard / membership
     are O(1) small-int ops at any fleet size (a single big-int mask pays a
@@ -329,7 +328,6 @@ class Simulator:
         fast_query: bool = True,
         n_shards: int | None = None,
         exchange_threshold: int | None = None,
-        vectorized: bool = False,
     ):
         self.spec = spec
         if n_shards is None:
@@ -350,13 +348,6 @@ class Simulator:
                                          seed=seed, fast_query=fast_query,
                                          **kwargs)
         self.n_shards = n_shards
-        # vectorized=True switches the event loop's per-worker state
-        # (free_time, speed multipliers) to numpy arrays and water-fills /
-        # rate-caps with array ops — the 100k-worker sweep path.  Float
-        # summation order differs from the scalar loop, so it is NOT
-        # byte-identical (completions and conservation are, timings agree
-        # to float tolerance); the scalar default stays the pinned path.
-        self.vectorized = vectorized
         self.models = kernel_models or paper_kernel_models()
         self._seed = seed
         self.rng = random.Random(seed ^ 0x5EED)
@@ -367,7 +358,7 @@ class Simulator:
         # sorted(idle) choice and running-TAO interference rescan;
         # fast_query=False keeps the PTT's scan queries.  Both slow paths
         # schedule byte-identically to the fast ones — they exist only as
-        # the baselines the perf suite (benchmarks/perf.py) measures against.
+        # test references (tests/test_fast_paths.py).
         self.fast_dispatch = fast_dispatch
 
     def _model_for(self, type_: str, impl: str) -> KernelModel:
@@ -472,7 +463,6 @@ class Simulator:
         self.core.reset_counters()
         n_workers = self.spec.n_workers
         fast = self.fast_dispatch
-        vec = self.vectorized
         sharded = self.n_shards is not None
         if sharded:
             # per-shard ready bitsets + O(1) queued-TAO counters: the load
@@ -483,15 +473,7 @@ class Simulator:
             nonempty_s = [_BitSet() for _ in range(n_shards)]
             qlen = [0] * n_shards
 
-        if vec:
-            free_time = np.zeros(n_workers, dtype=np.float64)
-            speed_np = np.asarray(self.speed_mult, dtype=np.float64)
-            cls_names = tuple(dict.fromkeys(self.spec.classes))
-            code_of = {c: i for i, c in enumerate(cls_names)}
-            cls_code = np.array([code_of[c] for c in self.spec.classes])
-        else:
-            free_time = [0.0] * n_workers
-        speed_vecs: dict = {}   # id(model) -> per-worker class-speed vector
+        free_time = [0.0] * n_workers
         queues = [deque() for _ in range(n_workers)]
         if fast:
             idle = _BitSet.full(n_workers)
@@ -573,16 +555,6 @@ class Simulator:
                     n += 1
             return n
 
-        def model_speed(model: KernelModel) -> np.ndarray:
-            """Per-worker class-speed vector for one kernel model (cached;
-            vectorized path only)."""
-            v = speed_vecs.get(id(model))
-            if v is None:
-                v = np.array([model.speed[self.spec.class_of(w)]
-                              for w in range(n_workers)])
-                speed_vecs[id(model)] = v
-            return v
-
         def push_queue(worker: int, tao: TAO) -> None:
             queues[worker].append(tao)
             if sharded:
@@ -655,103 +627,60 @@ class Simulator:
                 work *= cursor.remaining_fraction
             t_end = float("inf")
             chosen: list[int] = []
-            if vec:
-                # --- vectorized rates + water-fill (100k-worker path) ------
-                mem = np.asarray(members, dtype=np.intp)
-                mem_codes = np.unique(cls_code[mem])
-                n_conc = concurrent_same(tao.type, frozenset(
-                    cls_names[c] for c in mem_codes.tolist()))
-                s_a = model_speed(model)[mem] * speed_np[mem]
-                if model.stream and model.bw_cap:
-                    codes = cls_code[mem]
-                    for code in mem_codes.tolist():
-                        cap = model.bw_cap[cls_names[code]] / (1 + n_conc)
-                        msk = codes == code
-                        agg = float(s_a[msk].sum())
-                        if agg > cap > 0:
-                            s_a[msk] *= cap / agg
-                rates_a = s_a * (model.eff(width)
-                                 / (1.0 + model.cache_penalty * n_conc))
-                joins_a = np.maximum(free_time[mem], t0)
-                order = np.argsort(joins_a, kind="stable")
-                js = joins_a[order]
-                rs = rates_a[order]
-                rcum = np.cumsum(rs)
-                rjcum = np.cumsum(rs * js)
-                with np.errstate(divide="ignore", invalid="ignore"):
-                    cand_a = (work + rjcum) / rcum
-                nxt = np.empty_like(js)
-                if len(js) > 1:
-                    nxt[:-1] = js[1:]
-                nxt[-1] = np.inf
-                ok = (rcum > 0) & (cand_a >= js - 1e-12) \
-                    & (cand_a <= nxt + 1e-12)
-                hit_ks = np.flatnonzero(ok)
-                if hit_ks.size:
-                    ki = int(hit_ks[0])
-                    t_end = float(cand_a[ki])
-                    # .tolist() materializes python ints/floats, so nothing
-                    # numpy-typed ever reaches a TraceRecord repr
-                    chosen = mem[order[:ki + 1]].tolist()
-                    chosen_joins = js[:ki + 1]
-                    joins = dict(zip(chosen, chosen_joins.tolist()))
-            else:
-                # --- effective per-member rates (scalar pinned path) -------
-                n_conc = concurrent_same(
-                    tao.type, frozenset(cluster_of(m) for m in members))
-                rates = {}
-                per_cluster_speed: dict[str, float] = {}
-                for m in members:
-                    s = model.speed[cluster_of(m)] * self.speed_mult[m]
-                    per_cluster_speed[cluster_of(m)] = per_cluster_speed.get(
-                        cluster_of(m), 0.0) + s
-                    rates[m] = s
-                if model.stream and model.bw_cap:
-                    # cap aggregate streaming rate per cluster, shared with
-                    # other concurrent streaming TAOs touching the cluster
-                    for cl, agg in per_cluster_speed.items():
-                        cap = model.bw_cap[cl] / (1 + n_conc)
-                        if agg > cap > 0:
-                            scale_s = cap / agg
-                            for m in members:
-                                if cluster_of(m) == cl:
-                                    rates[m] *= scale_s
-                cache_factor = 1.0 + model.cache_penalty * n_conc
-                e = model.eff(width)
-                for m in rates:
-                    rates[m] = rates[m] * e / cache_factor
+            # --- effective per-member rates ----------------------------
+            n_conc = concurrent_same(
+                tao.type, frozenset(cluster_of(m) for m in members))
+            rates = {}
+            per_cluster_speed: dict[str, float] = {}
+            for m in members:
+                s = model.speed[cluster_of(m)] * self.speed_mult[m]
+                per_cluster_speed[cluster_of(m)] = per_cluster_speed.get(
+                    cluster_of(m), 0.0) + s
+                rates[m] = s
+            if model.stream and model.bw_cap:
+                # cap aggregate streaming rate per cluster, shared with
+                # other concurrent streaming TAOs touching the cluster
+                for cl, agg in per_cluster_speed.items():
+                    cap = model.bw_cap[cl] / (1 + n_conc)
+                    if agg > cap > 0:
+                        scale_s = cap / agg
+                        for m in members:
+                            if cluster_of(m) == cl:
+                                rates[m] *= scale_s
+            cache_factor = 1.0 + model.cache_penalty * n_conc
+            e = model.eff(width)
+            for m in rates:
+                rates[m] = rates[m] * e / cache_factor
 
-                # --- water-filling finish time -----------------------------
-                joins = {m: max(t0, free_time[m]) for m in members}
-                parts = sorted(members, key=lambda m: joins[m])
-                # single incremental prefix-sum pass: the k-candidate loop
-                # used to recompute sum(rates) / sum(rates*joins) from
-                # scratch per k (O(k^2) per TAO start).  Accumulating
-                # left-to-right performs the exact same float additions in
-                # the same order, so the finish times are bit-identical —
-                # just O(k).
-                rsum = 0.0
-                rjsum = 0.0
-                for k in range(1, len(parts) + 1):
-                    m = parts[k - 1]
-                    rsum += rates[m]
-                    rjsum += rates[m] * joins[m]
-                    if rsum <= 0:
-                        continue
-                    cand = (work + rjsum) / rsum
-                    # valid if every chosen member joins before cand and the
-                    # next member (if any) joins after cand
-                    if cand >= joins[m] - 1e-12 and (
-                        k == len(parts) or cand <= joins[parts[k]] + 1e-12
-                    ):
-                        t_end = cand
-                        chosen = parts[:k]
-                        break
+            # --- water-filling finish time -----------------------------
+            joins = {m: max(t0, free_time[m]) for m in members}
+            parts = sorted(members, key=lambda m: joins[m])
+            # single incremental prefix-sum pass: the k-candidate loop
+            # used to recompute sum(rates) / sum(rates*joins) from
+            # scratch per k (O(k^2) per TAO start).  Accumulating
+            # left-to-right performs the exact same float additions in
+            # the same order, so the finish times are bit-identical —
+            # just O(k).
+            rsum = 0.0
+            rjsum = 0.0
+            for k in range(1, len(parts) + 1):
+                m = parts[k - 1]
+                rsum += rates[m]
+                rjsum += rates[m] * joins[m]
+                if rsum <= 0:
+                    continue
+                cand = (work + rjsum) / rsum
+                # valid if every chosen member joins before cand and the
+                # next member (if any) joins after cand
+                if cand >= joins[m] - 1e-12 and (
+                    k == len(parts) or cand <= joins[parts[k]] + 1e-12
+                ):
+                    t_end = cand
+                    chosen = parts[:k]
+                    break
             if not chosen:  # all rates zero (fully failed place): fallback
                 chosen = [popper]
-                joins = {popper: max(t0, float(free_time[popper]))}
-                if vec:
-                    chosen_joins = np.array([joins[popper]])
+                joins = {popper: max(t0, free_time[popper])}
                 t_end = t0 + work / max(
                     model.speed[cluster_of(popper)] *
                     max(self.speed_mult[popper], 1e-6), 1e-9)
@@ -760,16 +689,10 @@ class Simulator:
                 # serialized before compute, delaying this segment's finish
                 t_end += move_cost
 
-            if vec:
-                busy_acc += t_end * len(chosen) - float(chosen_joins.sum())
-                free_time[np.asarray(chosen, dtype=np.intp)] = t_end
-                for m in chosen:
-                    idle.discard(m)
-            else:
-                for m in chosen:
-                    busy_acc += t_end - joins[m]
-                    free_time[m] = t_end
-                    idle.discard(m)
+            for m in chosen:
+                busy_acc += t_end - joins[m]
+                free_time[m] = t_end
+                idle.discard(m)
             rec = TraceRecord(tao.id, tao.type, leader, width,
                               t0, t_end, tuple(chosen), dag_id=tao.dag_id,
                               impl=tao.assigned_impl)
@@ -1008,8 +931,6 @@ class Simulator:
                     for w in ev.workers:
                         if w < n_workers and w not in self.failed:
                             self.speed_mult[w] = ev.speed
-                    if vec:
-                        speed_np[:] = self.speed_mult
                     continue
                 if ev.action == C_KILL:
                     newly = [w for w in ev.workers
@@ -1020,8 +941,6 @@ class Simulator:
                         self.failed.add(w)
                         self.speed_mult[w] = 0.0
                         idle.discard(w)
-                    if vec:
-                        speed_np[:] = self.speed_mult
                     dead = set(newly)
                     self.core.set_dead(frozenset(self.failed))
                     # 1) truncate running segments that lost a participant:
@@ -1102,8 +1021,6 @@ class Simulator:
                         free_time[w] = max(free_time[w], now)
                         revived.append(w)
                     self.speed_mult[w] = 1.0
-                if vec:
-                    speed_np[:] = self.speed_mult
                 self.core.set_dead(frozenset(self.failed))
                 for w in revived:
                     if not dispatch_from(w, now):

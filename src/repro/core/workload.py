@@ -384,14 +384,14 @@ class WorkloadResult(SimResult):
         return sum(s.preempted_count for s in self.per_dag.values())
 
     def preemptions_by_tenant(self) -> dict:
-        """``tenant -> displacement count`` — the fairness surface benches
-        assert on (e.g. the steady tenant is never the victim)."""
+        """``tenant -> displacement count`` — the fairness surface the
+        tests assert on (e.g. the steady tenant is never the victim)."""
         return {tenant: sum(s.preempted_count for s in stats)
                 for tenant, stats in self.per_tenant().items()}
 
     def failure_requeues_by_tenant(self) -> dict:
-        """``tenant -> TAO re-admissions caused by worker death`` (the
-        chaos bench's conservation/robustness surface; disjoint from
+        """``tenant -> TAO re-admissions caused by worker death`` (chaos's
+        conservation/robustness surface; disjoint from
         :meth:`preemptions_by_tenant`, which is policy displacements)."""
         return {tenant: sum(s.requeued_by_failure for s in stats)
                 for tenant, stats in self.per_tenant().items()}
@@ -404,10 +404,10 @@ class WorkloadResult(SimResult):
         return sum(s.preemption_delay for s in self.per_dag.values()) / n
 
     def goodput(self, slo) -> int:
-        """Completed DAGs whose sojourn met their SLO (the admission
-        bench's headline metric — a rejected or SLO-missing DAG is not
-        good output, however fast the rest ran).  ``slo`` as in
-        :func:`_slo_of`: float, ``tenant -> target`` dict, or callable."""
+        """Completed DAGs whose sojourn met their SLO (a rejected or
+        SLO-missing DAG is not good output, however fast the rest ran).
+        ``slo`` as in :func:`_slo_of`: float, ``tenant -> target`` dict, or
+        callable."""
         return sum(1 for s in self.per_dag.values()
                    if s.done and s.sojourn <= _slo_of(s, slo))
 
@@ -444,7 +444,7 @@ class WorkloadResult(SimResult):
 
     def token_throughput_by_tenant(self) -> dict:
         """``tenant -> delivered tokens / makespan`` — the per-tenant
-        serving throughput surface benches report."""
+        serving throughput."""
         if self.makespan <= 0:
             return {t: 0.0 for t in self.per_tenant()}
         return {t: toks / self.makespan

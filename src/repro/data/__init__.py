@@ -1,4 +1,4 @@
 """repro.data — deterministic synthetic token pipeline with host sharding."""
-from .pipeline import SyntheticLM, SyntheticFrames, make_batch_specs
+from .pipeline import SyntheticLM, SyntheticFrames
 
-__all__ = ["SyntheticLM", "SyntheticFrames", "make_batch_specs"]
+__all__ = ["SyntheticLM", "SyntheticFrames"]

@@ -6,8 +6,7 @@ assembly, deterministic seeding by (seed, step, host)) with a synthetic
 Zipf-ish token source so every example/benchmark is hermetic and offline.
 
 The generators are numpy-based (host-side, like a real input pipeline) and
-hand jax the final device arrays.  ``make_batch_specs`` mirrors each batch as
-ShapeDtypeStructs for the dry-run (same pattern as ``input_specs``).
+hand jax the final device arrays.
 """
 from __future__ import annotations
 
@@ -15,7 +14,6 @@ import dataclasses
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 
 
@@ -76,11 +74,3 @@ class SyntheticFrames:
         return {"frames": jnp.asarray(feats, jnp.bfloat16),
                 "targets": jnp.asarray(labels)}
 
-
-def make_batch_specs(batch: dict, shardings: dict | None = None) -> dict:
-    """ShapeDtypeStruct mirror of a batch (dry-run stand-in)."""
-    out = {}
-    for k, v in batch.items():
-        sh = None if shardings is None else shardings.get(k)
-        out[k] = jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=sh)
-    return out

@@ -1,1 +1,1 @@
-"""repro.launch — production mesh, dry-run driver, train/serve entry points."""
+"""repro.launch — mesh, tenant zoo, compile cache, train/serve entry points."""

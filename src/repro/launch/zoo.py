@@ -30,7 +30,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from ..core.dag import TAO, ImplVariant
+from ..core.dag import TAO
 from ..core.runtime import ChunkedWork
 from ..core.serve_orchestrator import ServeRequest
 
@@ -55,22 +55,13 @@ class ZooTenant:
 
     def __init__(self, name: str, flavor: str = "kernel",
                  slab_tokens: int = 1024, decode_steps: int = 1,
-                 seed: int = 0, multi_impl: bool = False):
+                 seed: int = 0):
         if flavor not in FLAVORS:
             raise ValueError(f"unknown flavor {flavor!r}; known: {FLAVORS}")
         self.name = name
         self.flavor = flavor
         self.slab_tokens = max(1, int(slab_tokens))
         self.decode_steps = max(1, int(decode_steps))
-        # multi_impl: bind every host-available kernel implementation
-        # (ops.available_impls(): ref + pallas on a TPU, ref + interpret on
-        # CPU) as TAO variants, so the scheduler picks the impl jointly
-        # with (leader, width).  Kernel flavor only — the
-        # model flavors run jitted whole-model payloads with no variant
-        # axis.  Off by default: single-variant tenants schedule
-        # byte-identically to the pre-variant zoo.
-        self.multi_impl = bool(multi_impl) and flavor == "kernel"
-        self._impl_payloads: dict = {}
         if flavor == "kernel":
             self._build_kernel_payloads(seed)
         else:
@@ -90,34 +81,22 @@ class ZooTenant:
         cache_slab = jax.random.normal(k3, (4 * S, H * D), jnp.float32)
         x1 = jax.random.normal(k0, (1, H * D), jnp.float32)
 
-        def make_prefill(attn_op, mm_op) -> Callable[[], None]:
-            def prefill_slab() -> None:
-                attn = attn_op(q, kv, kv)
-                y = mm_op(attn.reshape(S, H * D), w)
-                jax.block_until_ready(y)
-            return prefill_slab
+        def prefill_slab() -> None:
+            attn = ops.flash_attention(q, kv, kv)
+            y = ops.matmul(attn.reshape(S, H * D), w)
+            jax.block_until_ready(y)
 
-        def make_decode(copy_op) -> Callable[[], None]:
-            # the burst's GEMV is a single row, which no 128-multiple tile
-            # of the Pallas matmul divides, so it is pinned to XLA
-            # (force="ref") on every backend; a variant only swaps the copy
-            # kernel (the class-defining op)
-            def decode_burst() -> None:
-                for _ in range(self.decode_steps):
-                    moved = copy_op(cache_slab)
-                    y = ops.matmul(x1, w, force="ref")
-                    jax.block_until_ready((moved, y))
-            return decode_burst
+        # the burst's GEMV is a single row, which no 128-multiple tile of
+        # the Pallas matmul divides, so it is pinned to XLA (force="ref")
+        # on every backend; the copy keeps auto dispatch
+        def decode_burst() -> None:
+            for _ in range(self.decode_steps):
+                moved = ops.copy(cache_slab)
+                y = ops.matmul(x1, w, force="ref")
+                jax.block_until_ready((moved, y))
 
-        # default payloads keep auto dispatch (force=None): byte-identical
-        # single-variant behavior when multi_impl is off
-        self.prefill_slab = make_prefill(ops.flash_attention, ops.matmul)
-        self.decode_burst = make_decode(ops.copy)
-        if self.multi_impl:
-            for im in ops.available_impls():
-                self._impl_payloads[im.name] = (
-                    make_prefill(im.op("flash_attention"), im.op("matmul")),
-                    make_decode(im.op("copy")))
+        self.prefill_slab = prefill_slab
+        self.decode_burst = decode_burst
 
     def _build_model_payloads(self, arch: str, seed: int) -> None:
         from ..configs import get_smoke_config
@@ -152,9 +131,6 @@ class ZooTenant:
         """Compile all payloads now, off the worker threads."""
         self.prefill_slab()
         self.decode_burst()
-        for pf, df in self._impl_payloads.values():
-            pf()
-            df()
 
     def prefill_chunks(self, r: ServeRequest) -> int:
         return max(1, math.ceil(r.prompt_len / self.slab_tokens))
@@ -163,7 +139,7 @@ class ZooTenant:
         """Per-token KV-cache bytes this tenant's decode actually streams.
 
         Sized from the kernel flavor's decode slab (``4*S x H*D`` float32
-        standing for ``slab_tokens`` tokens of cache), so threaded-bench
+        standing for ``slab_tokens`` tokens of cache), so threaded serving
         footprints track the bytes the payload really touches — model
         flavors share the same figure for comparable footprints."""
         H, S, D = 4, 256, 64
@@ -171,36 +147,21 @@ class ZooTenant:
         return slab_bytes / float(self.slab_tokens)
 
     def bind(self, tao: TAO, r: ServeRequest) -> None:
-        """Attach this tenant's ChunkedWork payload to one serving TAO.
-
-        With ``multi_impl`` the TAO additionally carries one
-        :class:`~repro.core.dag.ImplVariant` per host-available kernel
-        implementation (identical chunk structure — the ChunkCursor is
-        variant-agnostic), and the policies choose which one executes."""
+        """Attach this tenant's ChunkedWork payload to one serving TAO."""
         n = self.prefill_chunks(r) if tao.type == "prefill" else 1
-        which = 0 if tao.type == "prefill" else 1
-        fn = self.prefill_slab if which == 0 else self.decode_burst
+        fn = (self.prefill_slab if tao.type == "prefill"
+              else self.decode_burst)
         tao.work = ChunkedWork(lambda i, fn=fn: fn(), n)
-        if self._impl_payloads:
-            tao.impls = tuple(
-                ImplVariant(name, ChunkedWork(lambda i, fn=fns[which]: fn(),
-                                              n))
-                for name, fns in self._impl_payloads.items())
-            tao.assigned_impl = tao.impls[0].name
 
 
 def default_zoo(flavors: dict | None = None, slab_tokens: int = 1024,
-                decode_steps: int = 1, seed: int = 0,
-                multi_impl: bool = False) -> dict:
+                decode_steps: int = 1, seed: int = 0) -> dict:
     """``tenant name -> ZooTenant``.  Default pairing mirrors the bursty
     trace: the latency-sensitive ``steady`` tenant serves a transformer,
-    the ``burst`` tenant hammers the raw Pallas-class kernels.
-    ``multi_impl=True`` lets kernel-flavor tenants expose every
-    host-available implementation as schedulable TAO variants."""
+    the ``burst`` tenant hammers the raw Pallas-class kernels."""
     flavors = flavors or {"steady": "transformer", "burst": "kernel"}
     return {name: ZooTenant(name, flavor=fl, slab_tokens=slab_tokens,
-                            decode_steps=decode_steps, seed=seed + i,
-                            multi_impl=multi_impl)
+                            decode_steps=decode_steps, seed=seed + i)
             for i, (name, fl) in enumerate(flavors.items())}
 
 

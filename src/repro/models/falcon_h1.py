@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import ops
-from ..parallel.sharding import constrain, logical_sharding
+from ..parallel.sharding import constrain
 from .layers import attention, decode_attention, rmsnorm
 from .losses import lm_cross_entropy
 from .mamba2 import mixer_full, mixer_param_defs, mixer_step, mixer_widths
@@ -193,29 +193,19 @@ class FalconH1LM(BaseModel):
         return loss, {"loss": loss}
 
     # -------------------------------------------------------------- serve --
-    def init_cache(self, batch_size: int, max_len: int, abstract=False):
+    def init_cache(self, batch_size: int, max_len: int):
         cfg = self.cfg
         L, H, N, P = (cfg.n_layers, cfg.ssm_heads, cfg.ssm_state,
                       cfg.ssm_head_dim)
         conv_dim, _ = mixer_widths(cfg)
-        kv = ((L, batch_size, cfg.n_kv_heads, max_len, cfg.hd),
-              ("layers", "batch", "kv_heads", "kv_seq", None), BF16)
-        shapes = {
-            "k": kv, "v": kv,
-            "conv": ((L, batch_size, cfg.ssm_conv - 1, conv_dim),
-                     ("layers", "batch", None, "ff"), BF16),
-            "ssd": ((L, batch_size, H, N, P),
-                    ("layers", "batch", None, None, None), F32),
-            "pos": ((), (), jnp.int32),
+        kv = (L, batch_size, cfg.n_kv_heads, max_len, cfg.hd)
+        return {
+            "k": jnp.zeros(kv, BF16), "v": jnp.zeros(kv, BF16),
+            "conv": jnp.zeros((L, batch_size, cfg.ssm_conv - 1, conv_dim),
+                              BF16),
+            "ssd": jnp.zeros((L, batch_size, H, N, P), F32),
+            "pos": jnp.zeros((), jnp.int32),
         }
-        out = {}
-        for name, (shape, names, dtype) in shapes.items():
-            if abstract:
-                sh = logical_sharding(shape, names) if shape else None
-                out[name] = jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
-            else:
-                out[name] = jnp.zeros(shape, dtype)
-        return out
 
     def prefill(self, params, batch, max_len: int | None = None):
         """Run the prompt; return the last position's logits and a cache

@@ -15,7 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..parallel.sharding import constrain, logical_sharding
+from ..parallel.sharding import constrain
 from .layers import (apply_rope, attention, decode_attention, rmsnorm,
                      swiglu)
 from .losses import lm_cross_entropy
@@ -172,34 +172,28 @@ class HymbaLM(BaseModel):
         return loss, {"loss": loss}
 
     # --------------------------------------------------------------- serve --
-    def init_cache(self, batch_size: int, max_len: int, abstract=False):
+    def init_cache(self, batch_size: int, max_len: int):
         cfg = self.cfg
         DI, N = cfg.d_inner_hybrid, cfg.ssm_state
         H = DI // cfg.ssm_head_dim
         conv_dim = DI + 2 * N
         P = cfg.ssm_head_dim
 
-        def mk(shape, names, dtype):
-            if abstract:
-                sh = logical_sharding(shape, names) if shape else None
-                return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
-            return jnp.zeros(shape, dtype)
-
         ks, vs = [], []
         for i in range(cfg.n_layers):
             eff = max_len if self._window(i) is None else min(
                 max_len, cfg.window)
             shape = (batch_size, cfg.n_kv_heads, eff, cfg.hd)
-            names = ("batch", "kv_heads", "kv_seq", None)
-            ks.append(mk(shape, names, jnp.bfloat16))
-            vs.append(mk(shape, names, jnp.bfloat16))
+            ks.append(jnp.zeros(shape, jnp.bfloat16))
+            vs.append(jnp.zeros(shape, jnp.bfloat16))
         return {
             "k": tuple(ks), "v": tuple(vs),
-            "conv": mk((cfg.n_layers, batch_size, cfg.ssm_conv - 1, conv_dim),
-                       ("layers", "batch", None, "ff"), jnp.bfloat16),
-            "ssd": mk((cfg.n_layers, batch_size, H, N, P),
-                      ("layers", "batch", None, None, None), jnp.float32),
-            "pos": mk((), (), jnp.int32),
+            "conv": jnp.zeros(
+                (cfg.n_layers, batch_size, cfg.ssm_conv - 1, conv_dim),
+                jnp.bfloat16),
+            "ssd": jnp.zeros((cfg.n_layers, batch_size, H, N, P),
+                             jnp.float32),
+            "pos": jnp.zeros((), jnp.int32),
         }
 
     def prefill(self, params, batch, max_len: int | None = None):
@@ -228,23 +222,10 @@ class HymbaLM(BaseModel):
         x = rmsnorm(x, params["final_norm.w"], cfg.norm_eps)
         logits = x[:, -1:] @ params["head.w"].astype(x.dtype)
         cache = {"k": tuple(ks), "v": tuple(vs),
-                 "conv": self._stack_states(convs, B, "conv"),
-                 "ssd": self._stack_states(ssds, B, "ssd"),
+                 "conv": jnp.stack(convs),
+                 "ssd": jnp.stack(ssds),
                  "pos": jnp.full((), S, jnp.int32)}
         return logits, cache
-
-    def _stack_states(self, xs: list, batch: int, kind: str):
-        """Stack per-layer states; 0-layer variants (dry-run cost
-        accounting) produce a (0, ...) array instead of crashing."""
-        if xs:
-            return jnp.stack(xs)
-        cfg = self.cfg
-        DI, N = cfg.d_inner_hybrid, cfg.ssm_state
-        if kind == "conv":
-            return jnp.zeros((0, batch, cfg.ssm_conv - 1, DI + 2 * N),
-                             jnp.bfloat16)
-        return jnp.zeros((0, batch, DI // cfg.ssm_head_dim, N,
-                          cfg.ssm_head_dim), jnp.float32)
 
     def decode_step(self, params, tokens, cache):
         cfg = self.cfg
@@ -320,7 +301,7 @@ class HymbaLM(BaseModel):
         x = rmsnorm(x, params["final_norm.w"], cfg.norm_eps)
         logits = x @ params["head.w"].astype(x.dtype)
         cache = {"k": tuple(new_k), "v": tuple(new_v),
-                 "conv": self._stack_states(new_conv, B, "conv"),
-                 "ssd": self._stack_states(new_ssd, B, "ssd"),
+                 "conv": jnp.stack(new_conv),
+                 "ssd": jnp.stack(new_ssd),
                  "pos": pos + 1}
         return logits, cache

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..parallel.sharding import constrain, logical_sharding
+from ..parallel.sharding import constrain
 from .layers import rmsnorm
 from .losses import lm_cross_entropy
 from .model_api import BaseModel, ModelConfig, ParamDef
@@ -403,25 +403,18 @@ class Mamba2LM(BaseModel):
         return loss, {"loss": loss}
 
     # --------------------------------------------------------------- serve --
-    def init_cache(self, batch_size: int, max_len: int, abstract=False):
+    def init_cache(self, batch_size: int, max_len: int):
         cfg = self.cfg
         N, H, P = cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
         conv_dim, _ = mixer_widths(cfg)
-        shapes = {
-            "conv": ((cfg.n_layers, batch_size, cfg.ssm_conv - 1, conv_dim),
-                     ("layers", "batch", None, "ff"), jnp.bfloat16),
-            "ssd": ((cfg.n_layers, batch_size, H, N, P),
-                    ("layers", "batch", None, None, None), jnp.float32),
-            "pos": ((), (), jnp.int32),
+        return {
+            "conv": jnp.zeros(
+                (cfg.n_layers, batch_size, cfg.ssm_conv - 1, conv_dim),
+                jnp.bfloat16),
+            "ssd": jnp.zeros((cfg.n_layers, batch_size, H, N, P),
+                             jnp.float32),
+            "pos": jnp.zeros((), jnp.int32),
         }
-        out = {}
-        for name, (shape, names, dtype) in shapes.items():
-            if abstract:
-                sh = logical_sharding(shape, names) if shape else None
-                out[name] = jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
-            else:
-                out[name] = jnp.zeros(shape, dtype)
-        return out
 
     def prefill(self, params, batch):
         """Encode the prompt; emit the final SSD/conv state as the cache."""

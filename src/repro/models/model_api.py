@@ -4,17 +4,13 @@ families (dense/MoE decoder, SSM, hybrid, encoder, VLM backbone).
 Every model exposes:
   * ``param_defs()``      — {name: ParamDef(shape, logical names, init)}
   * ``init(key)``         — concrete fp32 params
-  * ``abstract_params()`` — ShapeDtypeStructs **with shardings** from the
-                            active sharding context (dry-run input specs)
   * ``loss(params, batch)``              — scalar loss + metrics
   * ``prefill(params, batch)``           — logits + populated cache
   * ``decode_step(params, tokens, cache)`` — one-token serve step
-  * ``init_cache / abstract_cache``      — decode cache (concrete/abstract)
-  * ``input_specs(shape_name)``          — batch ShapeDtypeStructs per cell
+  * ``init_cache(batch_size, max_len)``  — zeroed decode cache
 
 Layer params are stacked along a leading "layers" dim and consumed by
-``lax.scan`` — the HLO stays one-layer-sized, which is what makes compiling
-56-layer x 8x22B programs for 512 host devices tractable.
+``lax.scan`` — the HLO stays one-layer-sized, however deep the model.
 """
 from __future__ import annotations
 
@@ -25,8 +21,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from ..parallel.sharding import (current_ctx, logical_sharding,
-                                 pad_to_multiple)
+from ..parallel.sharding import pad_to_multiple
 
 
 # ---------------------------------------------------------------------------
@@ -84,7 +79,6 @@ class ModelConfig:
     remat_policy: str = "nothing"        # nothing | dots
     attn_chunk: int = 1024               # q-chunked attention block
     dense_attn_max_seq: int = 1024       # S above this -> chunked attention
-    scan_layers: bool = True
     logits_chunk: int = 0                # 0 = unchunked CE
     ce_onehot: bool = False              # TP-safe cross-entropy (no vocab
                                          # all-gather); see models/losses.py
@@ -95,10 +89,6 @@ class ModelConfig:
                                          # instead of shift-concat (which
                                          # copies + reshards the whole cache
                                          # every step)
-    shard_kv_seq: bool = True            # shard the decode cache's seq dim
-                                         # over spare mesh axes; False trades
-                                         # replicated-cache HBM for removing
-                                         # the update-slice all-gathers
     decode_no_fsdp: bool = False         # decode cells: keep weights fully
                                          # sharded (ff over model+data)
                                          # instead of FSDP-gathering the full
@@ -167,7 +157,7 @@ def init_param(key: jax.Array, d: ParamDef) -> jax.Array:
 
 
 class BaseModel:
-    """Shared init / abstract-spec machinery."""
+    """Shared init machinery."""
 
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg
@@ -186,14 +176,6 @@ class BaseModel:
         for i, (name, d) in enumerate(sorted(defs.items())):
             params[name] = init_param(jax.random.fold_in(key, i), d)
         return params
-
-    def abstract_params(self) -> dict:
-        out = {}
-        for name, d in sorted(self.param_defs().items()):
-            sharding = logical_sharding(d.shape, d.names)
-            out[name] = jax.ShapeDtypeStruct(d.shape, d.dtype,
-                                             sharding=sharding)
-        return out
 
     def param_count(self) -> int:
         import numpy as np

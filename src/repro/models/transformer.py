@@ -162,15 +162,10 @@ class DecoderLM(BaseModel):
             layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(cfg),
                                       static_argnums=())
 
-        if cfg.scan_layers:
-            def body(carry, lp):
-                out, _ = layer_fn(lp, carry)
-                return out, None
-            x, _ = jax.lax.scan(body, x, stacked)
-        else:
-            for i in range(cfg.n_layers):
-                lp = {k: v[i] for k, v in stacked.items()}
-                x, _ = layer_fn(lp, x)
+        def body(carry, lp):
+            out, _ = layer_fn(lp, carry)
+            return out, None
+        x, _ = jax.lax.scan(body, x, stacked)
 
         x = rmsnorm(x, top["final_norm.w"], cfg.norm_eps)
         head = (top["embed.w"].T if cfg.tie_embeddings else top["head.w"])
@@ -273,21 +268,10 @@ class DecoderLM(BaseModel):
         cache = {"k": ks, "v": vs, "pos": jnp.full((), S, jnp.int32)}
         return logits, cache
 
-    def init_cache(self, batch_size: int, max_len: int,
-                   abstract: bool = False):
+    def init_cache(self, batch_size: int, max_len: int):
         cfg = self.cfg
         eff = min(max_len, cfg.window) if cfg.window else max_len
         shape = (cfg.n_layers, batch_size, cfg.n_kv_heads, eff, cfg.hd)
-        names = ("layers", "batch", "kv_heads",
-                 "kv_seq" if cfg.shard_kv_seq else None, None)
-        if abstract:
-            from ..parallel.sharding import logical_sharding
-            sh = logical_sharding(shape, names)
-            return {
-                "k": jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sh),
-                "v": jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sh),
-                "pos": jax.ShapeDtypeStruct((), jnp.int32),
-            }
         return {"k": jnp.zeros(shape, jnp.bfloat16),
                 "v": jnp.zeros(shape, jnp.bfloat16),
                 "pos": jnp.zeros((), jnp.int32)}

@@ -1,6 +1,5 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS device-count forcing here — smoke
-tests and benches must see the 1 real CPU device (the 512-device mesh is the
-dry-run's private business)."""
+tests must see the 1 real CPU device."""
 import os
 import sys
 

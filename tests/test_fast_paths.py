@@ -4,8 +4,8 @@ The perf refactor's contract is *byte-identical schedules*: every O(1)
 structure (PTT incremental aggregates, bitmask dispatch sets, interference
 counters, prefix-sum water-filling) must compute exactly what the
 O(n_workers) scan baselines compute — the speed-up comes from the data
-structure, never from a semantic shortcut.  These tests pin that contract;
-``benchmarks/perf.py`` re-checks it at fleet scale on every CI run.
+structure, never from a semantic shortcut.  These tests pin that contract,
+up to fleets of 256 workers.
 """
 import random
 import time
@@ -188,15 +188,19 @@ def test_interference_tracker_matches_rescan():
 
 
 # --------------------------------------------------- end-to-end equality --
+@pytest.mark.parametrize("n_big,n_little", [(12, 4), (48, 16), (192, 64)],
+                         ids=["16w", "64w", "256w"])
 @pytest.mark.parametrize("policy", ["molding:adaptive", "adaptive",
                                     "molding:weight", "crit-ptt"])
-def test_sim_fast_and_slow_paths_schedule_identically(policy):
+def test_sim_fast_and_slow_paths_schedule_identically(policy, n_big,
+                                                      n_little):
     """The acceptance gate: on a multi-DAG stream the fast paths
     (fast_dispatch + fast_query) must produce the byte-identical trace of
-    the O(n_workers) scan baselines for the same seed."""
+    the O(n_workers) scan baselines for the same seed, at fleet sizes where
+    the scans and the O(1) structures diverge most in cost."""
     def run(fast):
         wl = random_workload(n_dags=5, rate=4.0, n_tasks=50, seed=2)
-        sim = Simulator(fleet(12, 4), make_policy(policy), seed=9,
+        sim = Simulator(fleet(n_big, n_little), make_policy(policy), seed=9,
                         fast_dispatch=fast, fast_query=fast)
         return sim.run_workload(wl)
 

@@ -33,7 +33,7 @@ def test_serve_pin():
 
 
 def test_check_pins_empty():
-    # the aggregate checker the bench harness / CI smoke calls
+    # the aggregate checker test_shard.py also calls at n_shards=1
     assert check_pins() == []
 
 

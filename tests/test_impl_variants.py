@@ -301,7 +301,7 @@ def test_joint_no_worse_than_best_static_choice():
 
 # ----------------------------------------------------- A/B leg independence --
 def test_reset_learning_makes_legs_byte_identical():
-    """The benchmark harness's leg reset: leg B after reset_learning() must
+    """The A/B leg reset: leg B after reset_learning() must
     reproduce a fresh Simulator's leg B byte for byte — no PTT profile,
     threshold or RNG state may leak across legs."""
     models = _impl_models()

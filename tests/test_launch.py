@@ -1,5 +1,5 @@
-"""Entry-point plumbing: the compile cache, the peaks table, the kernel
-registry's variants per backend, and what ``serve.main`` reports."""
+"""Entry-point plumbing: the compile cache and what ``serve.main`` reports,
+batched and through the tenant zoo."""
 import pathlib
 
 import jax
@@ -7,9 +7,8 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
-from repro.kernels import ops
+from repro.core.serve_orchestrator import build_serving_dag
 from repro.launch import compile_cache, serve
-from repro.launch.device_peaks import device_peaks
 from repro.models import get_model
 
 REPO = pathlib.Path(__file__).resolve().parents[1]
@@ -41,27 +40,6 @@ def test_compile_cache_defaults_to_fixed_ignored_dir(monkeypatch,
     assert ".jax_cache/" in ignored
 
 
-@pytest.mark.parametrize("kind,known", [("TPU v5 lite", True),
-                                        ("TPU v4", False), ("cpu", False)])
-def test_device_peaks_known_kinds_only(kind, known):
-    if known:
-        peaks = device_peaks(kind)
-        assert (peaks.flops_bf16, peaks.hbm_bw) == (197e12, 819e9)
-        assert "v5e" in peaks.source
-    else:
-        with pytest.raises(KeyError, match="no published peaks"):
-            device_peaks(kind)
-
-
-@pytest.mark.parametrize("backend,want", [("tpu", ("ref", "pallas")),
-                                          ("cpu", ("ref", "interpret"))])
-def test_available_impls_never_offer_interpret_on_tpu(monkeypatch, backend,
-                                                      want):
-    monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    monkeypatch.setattr(ops, "_interpret_works", lambda: True)
-    assert tuple(im.name for im in ops.available_impls()) == want
-
-
 def test_serve_main_reports_what_it_served(monkeypatch):
     monkeypatch.setattr(serve, "enable_compile_cache", lambda: "")
     report = serve.main(["--arch", "llama3.2-1b", "--smoke", "--batch", "2",
@@ -82,3 +60,28 @@ def test_serve_main_reports_what_it_served(monkeypatch):
     np.testing.assert_allclose(np.asarray(report.logits[:, 0], np.float32),
                                np.asarray(want, np.float32),
                                rtol=3e-2, atol=3e-2)
+
+
+def test_serve_zoo_serves_both_tenants_and_conserves(monkeypatch):
+    """The bursty two-tenant trace through the tenant zoo on real threads,
+    with the token-bucket gate and critical-boost preemption: every
+    admitted request finishes, and TAOs and tokens are accounted per
+    request (what chip_smoke.py checks on the chip)."""
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: "")
+    report = serve.main(["--arch", "llama3.2-1b", "--zoo"])
+    res = report.stats.result
+    by_name = {f"req{r.id}": r for r in report.requests}
+    assert len(res.per_dag) == len(by_name)
+    admitted = []
+    for st in res.per_dag.values():
+        if not st.rejected:
+            assert st.done, f"admitted {st.name} did not finish"
+            admitted.append(by_name[st.name])
+    delivered = res.tokens_by_tenant()
+    assert {t for t, n in delivered.items() if n > 0} == {"steady", "burst"}
+    assert res.completed == sum(len(build_serving_dag([r]).nodes)
+                                for r in admitted)
+    want: dict[str, float] = {t: 0.0 for t in delivered}
+    for r in admitted:
+        want[r.tenant] += r.tokens
+    assert delivered == want

@@ -152,8 +152,7 @@ def test_affinity_charging_reduces_movement_sim():
     must raise the KV-cache hit rate and cut moved bytes.  Footprints are
     sized so the move penalty dominates the compute gap — at that scale the
     charged leg MUST follow residency while the legacy leg keeps hopping
-    (the marginal-penalty regime is the bench's business, not a unit
-    test's)."""
+    (the marginal-penalty regime is not a unit test's business)."""
     kv_heavy = 1e7
     res_on, _, _ = serving_footprint_run("sim", kv_heavy, charge=True)
     res_off, _, _ = serving_footprint_run("sim", kv_heavy, charge=False)

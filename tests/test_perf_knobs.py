@@ -48,7 +48,7 @@ def test_block_skip_attention_equals_masked(window):
 @pytest.mark.parametrize("knobs", [
     {"swa_ring_buffer": True},
     {"swa_ring_buffer": True, "decode_no_fsdp": True},
-    {"shard_kv_seq": False},
+    {"decode_no_fsdp": True},
 ])
 def test_swa_decode_knobs_match_forward(knobs):
     """Ring buffer / decode layouts: teacher-forced decode past the window

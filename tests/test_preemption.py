@@ -349,9 +349,8 @@ def test_critical_boost_controller_decisions():
 
 # ------------------------------------------------------------------ fairness
 def test_backlog_steady_tenant_never_displaced():
-    """The fairness surface the bench asserts on: on the bursty stream the
-    steady tenant's DAGs are never the displacement victim, on either
-    vehicle."""
+    """The fairness surface: on the bursty stream the steady tenant's DAGs
+    are never the displacement victim, on either vehicle."""
     sim = Simulator(fleet(48, 16), make_policy("molding:adaptive"), seed=1)
     res = sim.run_workload(_chunked_bursty(seed=1), admission=_slo_gate(),
                            preemption=make_preemption("backlog"))

@@ -183,30 +183,6 @@ def test_sharded_reset_counters_clears_exchange_state():
     assert st["in"] == [0] * 4 and st["out"] == [0] * 4
 
 
-# --------------------------------------------------------- vectorized mode --
-
-
-def test_vectorized_event_loop_agrees_with_scalar():
-    """The numpy event loop is not byte-identical (float summation order)
-    but must complete the same work with float-tolerance-equal timing."""
-    wl = lambda: random_workload(n_dags=6, rate=20.0, n_tasks=40, seed=3)
-    spec, pol = fleet(48, 16), "molding:adaptive"
-    scalar = Simulator(spec, make_policy(pol), seed=1).run_workload(wl())
-    vec = Simulator(spec, make_policy(pol), seed=1,
-                    vectorized=True).run_workload(wl())
-    assert vec.completed == scalar.completed == wl().total_taos()
-    assert vec.makespan == pytest.approx(scalar.makespan, rel=1e-6)
-
-
-def test_vectorized_sharded_conserves():
-    wl = _CONS_WL()
-    sim = Simulator(_CONS_SPEC(), make_policy("molding:adaptive"), seed=1,
-                    n_shards=4, vectorized=True)
-    res = sim.run_workload(wl)
-    assert res.completed == wl.total_taos()
-    assert sim.core.exchange_conserved()
-
-
 # ------------------------------------------------------------ _BitSet unit --
 
 

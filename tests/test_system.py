@@ -66,22 +66,3 @@ def test_data_pipeline_determinism_and_sharding():
     # different steps differ
     assert not np.array_equal(np.asarray(a["tokens"]),
                               np.asarray(full_a.batch(1)["tokens"]))
-
-
-def test_dryrun_cell_machinery_importable():
-    """The dry-run module must not pollute device state when imported by
-    other code paths (it sets XLA_FLAGS at import; only check the helpers)."""
-    from repro.launch.dryrun import _shape_bytes, collective_bytes
-    assert _shape_bytes("bf16[128,256]") == 128 * 256 * 2
-    assert _shape_bytes("(f32[4,4], u8[16])") == 64 + 16
-    hlo = """
-      %ag = bf16[512,128]{1,0} all-gather(%x), replica_groups={}
-      %ar.1 = f32[1024]{0} all-reduce-start(%y), to_apply=%sum
-      %cp = u32[8]{0} collective-permute(%z), source_target_pairs={{0,1}}
-      %dot = f32[4,4]{1,0} dot(%a, %b)
-    """
-    got = collective_bytes(hlo)
-    assert got["all-gather"] == 512 * 128 * 2
-    assert got["all-reduce"] == 4096
-    assert got["collective-permute"] == 32
-    assert got["count"] == 3
